@@ -55,6 +55,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import QuantConfig, QuantPolicy
 from repro.data import DataPipeline, lm_batch, permutation_table
+from repro.launch.mesh import make_mesh
 from repro.models.lm import LMConfig, lm_init
 from repro.optim import adamw, constant
 from repro.train import (TrainConfig, init_state, make_optimizer,
@@ -213,7 +214,7 @@ def distributed(step, make_state, batch_fn, n_steps,
     2-shard save — with the cross-host fingerprint + replica audit on
     every step and zero tolerance for violations."""
     n_hosts = host_plan_args["n_hosts"]
-    mesh = jax.make_mesh(DIST_MESH, ("data", "model"))
+    mesh = make_mesh(DIST_MESH, ("data", "model"))
     rep = NamedSharding(mesh, P())
 
     def make_state_mesh():

@@ -17,14 +17,14 @@ import time
 from .common import write_bench_json
 
 
-def main() -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names")
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--json-dir", default=None,
                     help="write BENCH_<name>.json artifacts here")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     from . import (bench_fp4, bench_kernels, bench_lm_quant, bench_opt_step,
                    bench_penalty_placement, bench_quadratic,
@@ -44,6 +44,7 @@ def main() -> None:
         "train": (lambda: bench_train_robustness.main(fast=args.fast)),
     }
     only = set(args.only.split(",")) if args.only else None
+    failed = []
     print("name,us_per_call,derived")
     for name, fn in benches.items():
         if only and name not in only:
@@ -53,11 +54,15 @@ def main() -> None:
             rec = fn()
             if args.json_dir is not None and isinstance(rec, dict):
                 print(f"wrote {write_bench_json(name, rec, args.json_dir)}")
-        except Exception as e:  # keep the harness going
+        except Exception as e:  # run the rest, then exit nonzero
+            failed.append(name)
             print(f"{name}_FAILED,0,{type(e).__name__}:{e}", file=sys.stderr)
             print(f"{name}_failed,0,error={type(e).__name__}")
         print(f"bench_{name}_total,{(time.time()-t0)*1e6:.0f},wall")
+    if failed:
+        print(f"benches failed: {','.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -2,18 +2,43 @@
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
 
-"""Shared Pallas/TPU compatibility helpers for the kernel packages."""
+"""Shared helpers for the Pallas kernel packages."""
 
 from __future__ import annotations
 
-from jax.experimental.pallas import tpu as pltpu
+import re
+from collections import Counter
+from typing import Optional
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams around 0.5;
-# every kernel routes through this alias so the package works on both.
-TPUCompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+import jax
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-portable ``compiler_params`` for ``pl.pallas_call``."""
-    return TPUCompilerParams(**kwargs)
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode for one ``pallas_call``.
+
+    An explicit flag wins.  Otherwise kernels compile with Mosaic on a TPU
+    backend and run in the Pallas interpreter on every other backend (the
+    CPU test suite exercises the same kernel dataflow that way).  This is
+    the one place that decision is made.
+    """
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
+
+
+# the name scope a pallas_call opens, e.g. ".../opt_step/pallas_call" or,
+# under vmap, ".../vmap(opt_step)/pallas_call"
+_KERNEL_OP = re.compile(r'op_name="[^"]*/([^/"]+)/pallas_call"')
+
+
+def compiled_kernels(hlo_text: str) -> Counter:
+    """Count the Mosaic kernels in a compiled program's HLO text, by the
+    ``name`` each ``pallas_call`` is given: one count per
+    ``tpu_custom_call`` instruction.  A kernel that fell back to the jnp
+    path or ran in interpret mode leaves no such instruction."""
+    out: Counter = Counter()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _KERNEL_OP.search(line)
+            out[re.findall(r"\w+", m.group(1))[-1] if m else "?"] += 1
+    return out

@@ -1,10 +1,11 @@
 """Public entry point for the fused quantized decode-attention kernel.
 
 Same dispatch rule as ``wq_matmul``/``opt_step``: compiled Pallas on
-TPU, interpret mode elsewhere (so CPU CI exercises the identical kernel
-dataflow).  The wrapper is jitted with the geometry-independent knobs
-static; callers route through ``models/layers.py::attn_decode``, which
-consults the ``use_kernel`` auto-default before getting here.
+TPU, interpret mode elsewhere (``repro.kernels.resolve_interpret``), so
+CPU CI exercises the identical kernel dataflow.  The wrapper is jitted
+with the geometry-independent knobs static; callers route through
+``models/layers.py::attn_decode``, which consults the ``use_kernel``
+auto-default before getting here.
 """
 
 from __future__ import annotations
@@ -14,10 +15,6 @@ import functools
 import jax
 
 from .decode_attn import decode_attn_paged_pallas, decode_attn_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit,
@@ -30,7 +27,7 @@ def decode_attn(q, k_codes, k_scale, v_codes, v_scale, pos, *,
     (b, L, g, 1), per-row positions (b,)) -> (b, g, rep, hd)."""
     return decode_attn_pallas(q, k_codes, k_scale, v_codes, v_scale, pos,
                               bits=bits, window=window, softcap=softcap,
-                              block_l=block_l, interpret=_interpret())
+                              block_l=block_l)
 
 
 @functools.partial(jax.jit,
@@ -44,4 +41,4 @@ def decode_attn_paged(q, k_codes, k_scale, v_codes, v_scale,
     positions (b,) -> (b, g, rep, hd)."""
     return decode_attn_paged_pallas(
         q, k_codes, k_scale, v_codes, v_scale, block_tables, pos,
-        bits=bits, window=window, softcap=softcap, interpret=_interpret())
+        bits=bits, window=window, softcap=softcap)

@@ -21,11 +21,35 @@ from __future__ import annotations
 
 import functools
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 from repro.kernels.quant.quant_blockwise import _fp4_neighbors
+
+# one penalty partial per grid step, as an (8, 128) f32 VMEM block
+PEN_TILE = (8, 128)
+
+
+def tile_partial(x):
+    """``PEN_TILE`` block holding ``sum(x)`` at [0, 0] and zeros elsewhere,
+    so summing a kernel's whole partials output gives the exact total."""
+    total = jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0,
+                    keepdims=True)                       # (1, 1)
+    r = jax.lax.broadcasted_iota(jnp.int32, PEN_TILE, 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, PEN_TILE, 1)
+    return jnp.where((r == 0) & (c == 0), total, 0.0)
+
+
+def pen_spec_and_shape(grid):
+    """BlockSpec + output shape of the per-tile penalty partials."""
+    spec = pl.BlockSpec(PEN_TILE, lambda i, j: (i, j))
+    shape = jax.ShapeDtypeStruct(
+        (grid[0] * PEN_TILE[0], grid[1] * PEN_TILE[1]), jnp.float32)
+    return spec, shape
 
 
 def _neighbors_int(wb, s, qmax):
@@ -60,7 +84,7 @@ def _reg_kernel(w_ref, f_ref, grad_ref, pen_ref, *, qmax, bs, fp4):
     lo, hi = _blockwise_neighbors(w, bs, qmax, fp4)
     var = (hi - w) * (w - lo)
     grad_ref[...] = (0.5 * f * (lo + hi - 2.0 * w)).astype(grad_ref.dtype)
-    pen_ref[0, 0] = 0.5 * jnp.sum(f * var)
+    pen_ref[...] = tile_partial(0.5 * (f * var))
 
 
 def _reg_kernel_pretensor(w_ref, f_ref, s_ref, grad_ref, pen_ref, *, qmax, fp4):
@@ -70,23 +94,23 @@ def _reg_kernel_pretensor(w_ref, f_ref, s_ref, grad_ref, pen_ref, *, qmax, fp4):
     lo, hi = _neighbors_fp4(w, s) if fp4 else _neighbors_int(w, s, qmax)
     var = (hi - w) * (w - lo)
     grad_ref[...] = (0.5 * f * (lo + hi - 2.0 * w)).astype(grad_ref.dtype)
-    pen_ref[0, 0] = 0.5 * jnp.sum(f * var)
+    pen_ref[...] = tile_partial(0.5 * (f * var))
 
 
 def lotion_reg_pallas(w2d, f2d, *, qmax: float, block_size: int,
                       fp4: bool = False, scale=None,
                       tile_m: int = 8, tile_n: int = 1024,
-                      interpret: bool = True):
-    """Returns (grad (R, C), penalty_partials (grid_m, grid_n))."""
+                      interpret: Optional[bool] = None):
+    """Returns (grad (R, C), penalty partials); the penalty is the sum of
+    the partials array."""
     R, C = w2d.shape
     tile_n = min(tile_n, C)
     tile_m = min(tile_m, R)
     assert R % tile_m == 0 and C % tile_n == 0
     grid = (R // tile_m, C // tile_n)
     tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j))
-    pen_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    out_shape = (jax.ShapeDtypeStruct((R, C), w2d.dtype),
-                 jax.ShapeDtypeStruct(grid, jnp.float32))
+    pen_spec, pen_shape = pen_spec_and_shape(grid)
+    out_shape = (jax.ShapeDtypeStruct((R, C), w2d.dtype), pen_shape)
 
     if scale is None:
         assert tile_n % block_size == 0
@@ -101,5 +125,5 @@ def lotion_reg_pallas(w2d, f2d, *, qmax: float, block_size: int,
     return pl.pallas_call(
         kern, grid=grid, in_specs=in_specs,
         out_specs=(tile, pen_spec), out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret), name="lotion_reg",
     )(*args)

@@ -19,10 +19,6 @@ from repro.core.formats import CodebookFormat, get_format
 from .lotion_reg import lotion_reg_pallas
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _to_2d(w, block_size: int):
     """Flatten to (R, C): C = one-or-more whole blocks, R padded to the
     8-row sublane tile."""
@@ -57,7 +53,7 @@ def _fused(w, fisher, fmt_name: str, block_size: int):
             f2, _ = _to_2d(fisher, 1024)
             grad2, pen = lotion_reg_pallas(
                 w2, f2, qmax=qmax, block_size=-1, fp4=fp4,
-                scale=scale.astype(jnp.float32), interpret=_interpret())
+                scale=scale.astype(jnp.float32))
             flat = grad2.reshape(-1)
             if n_pad:
                 flat = flat[:-n_pad]
@@ -76,7 +72,7 @@ def _fused(w, fisher, fmt_name: str, block_size: int):
     w2, n_pad = _to_2d(w, block_size)
     f2, _ = _to_2d(fisher, block_size)
     grad2, pen = lotion_reg_pallas(w2, f2, qmax=qmax, block_size=block_size,
-                                   fp4=fp4, interpret=_interpret())
+                                   fp4=fp4)
     flat = grad2.reshape(-1)
     if n_pad:
         flat = flat[:-n_pad]

@@ -4,7 +4,7 @@
 pure-jnp oracle (the unfused update chain's math, leaf-local).
 """
 
-from .ops import fused_opt_step_leaf
+from .ops import fused_opt_step_leaf, fused_opt_step_sharded
 from .ref import opt_step_ref
 
-__all__ = ["fused_opt_step_leaf", "opt_step_ref"]
+__all__ = ["fused_opt_step_leaf", "fused_opt_step_sharded", "opt_step_ref"]

@@ -44,15 +44,19 @@ Penalty modes (static):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels import resolve_interpret
 from repro.kernels.lotion_reg.lotion_reg import (_blockwise_neighbors,
                                                 _neighbors_fp4,
-                                                _neighbors_int)
+                                                _neighbors_int,
+                                                pen_spec_and_shape,
+                                                tile_partial)
 
 # scalar-operand layout (one (1, 8) f32 row, lane-aligned)
 SC_LR, SC_BC1, SC_BC2, SC_CLIP, SC_SCALE, SC_OK = 0, 1, 2, 3, 4, 5
@@ -74,7 +78,7 @@ def _opt_kernel(w_ref, g_ref, mu_ref, nu_ref, sc_ref,
     g = g * sc_ref[0, SC_CLIP]
 
     if penalty_mode == "none":
-        pen_ref[0, 0] = jnp.zeros((), jnp.float32)
+        pen_ref[...] = jnp.zeros_like(pen_ref)
     else:
         if penalty_mode == "scalar":
             s = sc_ref[0, SC_SCALE]
@@ -86,7 +90,7 @@ def _opt_kernel(w_ref, g_ref, mu_ref, nu_ref, sc_ref,
         # into the cotangent first) — f is the PRE-update nu
         ct = (0.5 * lam) * nu
         g = g + (ct * (hi - w) - ct * (w - lo))
-        pen_ref[0, 0] = 0.5 * jnp.sum(nu * ((hi - w) * (w - lo)))
+        pen_ref[...] = tile_partial(0.5 * (nu * ((hi - w) * (w - lo))))
 
     if core == "adamw":
         mu2 = b1 * mu + (1 - b1) * g
@@ -119,10 +123,11 @@ def opt_step_pallas(w2d, g2d, mu2d, nu2d, scalars, *,
                     core: str = "adamw", momentum: float = 0.0,
                     fisher_decay=None,
                     tile_m: int = 8, tile_n: int = 1024,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Fused step over a 2-D leaf view.
 
-    Returns ``(new_w (R, C), new_mu, new_nu, pen_partials (gm, gn))``;
+    Returns ``(new_w (R, C), new_mu, new_nu, pen_partials)`` (the penalty
+    is the sum of ``pen_partials``);
     ``scalars`` is the (1, 8) [lr, bc1, bc2, clip_scale, scale, ok, ...]
     row (``ok`` = the non-finite guard flag; 0 freezes w/mu/nu in-kernel).
     """
@@ -137,11 +142,11 @@ def opt_step_pallas(w2d, g2d, mu2d, nu2d, scalars, *,
 
     tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j))
     sc_spec = pl.BlockSpec((1, N_SCALARS), lambda i, j: (0, 0))
-    pen_spec = pl.BlockSpec((1, 1), lambda i, j: (i, j))
+    pen_spec, pen_shape = pen_spec_and_shape(grid)
     out_shape = (jax.ShapeDtypeStruct((R, C), w2d.dtype),
                  jax.ShapeDtypeStruct((R, C), mu2d.dtype),
                  jax.ShapeDtypeStruct((R, C), nu2d.dtype),
-                 jax.ShapeDtypeStruct(grid, jnp.float32))
+                 pen_shape)
 
     kern = functools.partial(
         _opt_kernel, b1=b1, b2=b2, eps=eps, wd=weight_decay, lam=lam,
@@ -152,7 +157,7 @@ def opt_step_pallas(w2d, g2d, mu2d, nu2d, scalars, *,
         in_specs=[tile, tile, tile, tile, sc_spec],
         out_specs=(tile, tile, tile, pen_spec),
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret), name="opt_step",
     )(w2d, g2d, mu2d, nu2d, scalars)

@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the fused quant kernels.
 
 Handles arbitrary tensor ranks (reshape to the kernel's 2-D layout with
-lane-aligned padding), format dispatch (INT-n grids / FP4 e2m1), and the
-interpret-mode switch (CPU container -> interpret=True; TPU -> Mosaic).
+lane-aligned padding) and format dispatch (INT-n grids / FP4 e2m1); the
+kernel itself picks Mosaic or interpret mode (``resolve_interpret``).
 """
 
 from __future__ import annotations
@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from repro.core.formats import CodebookFormat, get_format
 
 from .quant_blockwise import quant_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _to_2d(w, block_size: int):
@@ -55,11 +51,10 @@ def quant_rtn(w, fmt_name: str = "int4", block_size: int = 256):
         scale = jnp.where(absmax > 0, absmax / qmax, 1.0).astype(jnp.float32)
         w2, n_pad = _to_2d(w, 1024)
         out = quant_pallas(w2, qmax=qmax, block_size=-1, fp4=fp4,
-                           scale=scale, interpret=_interpret())
+                           scale=scale)
     else:
         w2, n_pad = _to_2d(w, block_size)
-        out = quant_pallas(w2, qmax=qmax, block_size=block_size, fp4=fp4,
-                           interpret=_interpret())
+        out = quant_pallas(w2, qmax=qmax, block_size=block_size, fp4=fp4)
     flat = out.reshape(-1)
     if n_pad:
         flat = flat[:-n_pad]
@@ -80,12 +75,12 @@ def quant_rr(w, key, fmt_name: str = "int4", block_size: int = 256):
         w2, n_pad = _to_2d(w, 1024)
         noise = jax.random.uniform(key, w2.shape, dtype=jnp.float32)
         out = quant_pallas(w2, qmax=qmax, block_size=-1, fp4=fp4,
-                           noise=noise, scale=scale, interpret=_interpret())
+                           noise=noise, scale=scale)
     else:
         w2, n_pad = _to_2d(w, block_size)
         noise = jax.random.uniform(key, w2.shape, dtype=jnp.float32)
         out = quant_pallas(w2, qmax=qmax, block_size=block_size, fp4=fp4,
-                           noise=noise, interpret=_interpret())
+                           noise=noise)
     flat = out.reshape(-1)
     if n_pad:
         flat = flat[:-n_pad]

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 E2M1_POS = (0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0)
 
 
@@ -114,7 +116,7 @@ def quant_pallas(w2d: jnp.ndarray, *, qmax: float, block_size: int,
                  fp4: bool = False, noise: Optional[jnp.ndarray] = None,
                  scale: Optional[jnp.ndarray] = None,
                  tile_m: int = 8, tile_n: int = 1024,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     """w2d: (R, C).  blockwise when ``scale is None`` (block_size | tile_n),
     else per-tensor with the precomputed (1,1) ``scale``."""
     R, C = w2d.shape
@@ -144,5 +146,5 @@ def quant_pallas(w2d: jnp.ndarray, *, qmax: float, block_size: int,
         in_specs=in_specs,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((R, C), w2d.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret), name="quant_blockwise",
     )(*args)

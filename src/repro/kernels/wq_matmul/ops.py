@@ -11,10 +11,6 @@ from .wq_matmul import (wq_matmul_pallas, wqt_matmul_a8_pallas,
                         wqt_matmul_pallas)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def pack_weight(w, block_k: int = 128, bits: int = 4):
     """(K, N) fp -> (codes, scales) in the kernel layout."""
     return quantize_weights_ref(w, block_k, bits)
@@ -27,8 +23,7 @@ def wq_matmul(x, codes, scales, block_k: int = 128, bits: int = 4,
     """x (M, K) @ dequant(codes, scales); the M edge (ragged decode
     batches) is padded to the tile grid inside the pallas wrapper."""
     return wq_matmul_pallas(x, codes, scales, block_k=block_k,
-                            int4=(bits == 4), tile_m=tile_m, tile_n=tile_n,
-                            interpret=_interpret())
+                            int4=(bits == 4), tile_m=tile_m, tile_n=tile_n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "bits",
@@ -40,8 +35,7 @@ def wqt_matmul(x, codes, scales, block_k: int = -1, bits: int = 8,
     (1, 1) scale; otherwise blockwise (N, K//bs) scales.  M/N edges are
     padded internally."""
     return wqt_matmul_pallas(x, codes, scales, block_k=block_k,
-                             int4=(bits == 4), tile_m=tile_m, tile_n=tile_n,
-                             interpret=_interpret())
+                             int4=(bits == 4), tile_m=tile_m, tile_n=tile_n)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "bits",
@@ -54,4 +48,4 @@ def wqt_matmul_a8(xq, xs, codes, scales, block_k: int = -1, bits: int = 8,
     dequant-free fp32 scale epilogue.  Returns fp32 (M, N)."""
     return wqt_matmul_a8_pallas(xq, xs, codes, scales, block_k=block_k,
                                 int4=(bits == 4), tile_m=tile_m,
-                                tile_n=tile_n, interpret=_interpret())
+                                tile_n=tile_n)

@@ -48,6 +48,8 @@ from repro.core import (QuantPolicy, cast_params, get_format,  # noqa: E402
                         qtensor_use_kernel)
 from repro.core.formats import IntFormat  # noqa: E402
 from repro.distributed import params_shardings  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import mesh_from_arg  # noqa: E402
 from repro.models.lm import lm_decode, lm_init, lm_prefill  # noqa: E402
 
 
@@ -142,7 +144,7 @@ def _chaos(cfg, params, args, use_kernel, kv_quant):
     print("invariants: 0 violations, all requests terminal")
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -190,14 +192,13 @@ def main():
                          "stragglers, eviction storms, malformed/burst "
                          "submissions) with per-tick invariant audit")
     ap.add_argument("--chaos-seed", type=int, default=13)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-        mesh = jax.make_mesh(shape, axes)
-    else:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+
+def run(args):
+    """Serve as ``args`` says (static prefill+decode, ``--scheduler``
+    replay, or ``--chaos`` replay)."""
+    mesh = mesh_from_arg(args.mesh)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = lm_init(jax.random.PRNGKey(0), cfg)
@@ -269,6 +270,12 @@ def main():
     print(f"prefill: {args.batch}x{args.prompt_len} in {t_prefill:.3f}s | "
           f"decode: {n_tok} tokens in {t_decode:.3f}s "
           f"({n_tok/t_decode:.1f} tok/s)")
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -33,13 +33,55 @@ from repro.data import DataPipeline, lm_batch, permutation_table  # noqa: E402
 from repro.distributed import state_shardings, train_batch_shardings  # noqa: E402
 from repro.distributed.context import set_constraints  # noqa: E402
 from repro.launch import specs as sp  # noqa: E402
+from repro.launch.cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import mesh_from_arg  # noqa: E402
 from repro.models.lm import lm_init  # noqa: E402
 from repro.optim import adamw, cosine_with_warmup  # noqa: E402
 from repro.train import (TrainConfig, init_state, make_optimizer,  # noqa: E402
                          make_train_step, run_loop)
 
 
-def main():
+def shard_setup(cfg, opt, mesh):
+    """Train-state shardings on ``mesh`` for the chain ``opt``, with the
+    activation constraints installed for the model's roles."""
+    state_abs = jax.eval_shape(
+        lambda k: init_state(lm_init(k, cfg), opt), jax.random.PRNGKey(0))
+    state_sh = state_shardings(mesh, state_abs)
+    set_constraints(residual=NamedSharding(mesh, P(("data",), None, "model")),
+                    logits=NamedSharding(
+                        mesh, P(("data",), None, None, "model")
+                        if cfg.n_codebooks > 1 else P(("data",), None, "model")),
+                    head_in=NamedSharding(mesh, P(("data",), None, None)))
+    return state_sh
+
+
+def init_sharded_state(cfg, opt, state_sh, seed: int = 0):
+    """Initial train state (model init from ``seed``), placed per
+    ``state_sh``."""
+    return jax.jit(lambda k: init_state(lm_init(k, cfg), opt),
+                   out_shardings=state_sh)(jax.random.PRNGKey(seed))
+
+
+def make_batch_fn(cfg, batch: int, seq: int):
+    """Step index -> the seeded synthetic LM batch of that step."""
+    perm = permutation_table(0, cfg.vocab)
+
+    def batch_fn(s):
+        return lm_batch(0, s, batch, seq, cfg.vocab, perm,
+                        n_codebooks=cfg.n_codebooks)
+    return batch_fn
+
+
+def make_pipeline(cfg, mesh, batch: int, seq: int):
+    """Prefetching pipeline of ``make_batch_fn`` batches, data-sharded on
+    ``mesh``."""
+    batch_sh = train_batch_shardings(
+        mesh, sp.train_batch_specs(cfg, batch, seq), batch)
+    return DataPipeline(make_batch_fn(cfg, batch, seq), sharding=batch_sh,
+                        prefetch=1)
+
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -61,17 +103,17 @@ def main():
                          "instead of a plain run; exits nonzero on any "
                          "audit violation")
     ap.add_argument("--chaos-seed", type=int, default=1)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.chaos and args.microbatches != 1:
         ap.error("--chaos requires --microbatches 1 (the poison scalar "
                  "is per batch)")
+    return args
 
-    if args.mesh:
-        shape = tuple(int(x) for x in args.mesh.split("x"))
-        axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
-        mesh = jax.make_mesh(shape, axes)
-    else:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+
+def run(args):
+    """Train as ``args`` says; returns ``run_loop``'s result (the chaos
+    drive exits with its verdict instead)."""
+    mesh = mesh_from_arg(args.mesh)
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     qcfg = QuantConfig(method=args.method, fmt_name="int4", lam=args.lam,
@@ -82,14 +124,7 @@ def main():
     # drives init_state, the sharding specs, and the step
     opt = make_optimizer(tcfg, adamw(cosine_with_warmup(args.lr, 5, args.steps)))
 
-    state_abs = jax.eval_shape(
-        lambda k: init_state(lm_init(k, cfg), opt), jax.random.PRNGKey(0))
-    state_sh = state_shardings(mesh, state_abs)
-    set_constraints(residual=NamedSharding(mesh, P(("data",), None, "model")),
-                    logits=NamedSharding(
-                        mesh, P(("data",), None, None, "model")
-                        if cfg.n_codebooks > 1 else P(("data",), None, "model")),
-                    head_in=NamedSharding(mesh, P(("data",), None, None)))
+    state_sh = shard_setup(cfg, opt, mesh)
 
     # simulated host count: one host per data/pod-axis slice of the mesh
     # (the replica groups a real multi-host job would place one process
@@ -102,11 +137,7 @@ def main():
         if ax != "model":
             n_hosts *= int(n)
 
-    perm = permutation_table(0, cfg.vocab)
-
-    def batch_fn(s):
-        return lm_batch(0, s, args.batch, args.seq, cfg.vocab, perm,
-                        n_codebooks=cfg.n_codebooks)
+    batch_fn = make_batch_fn(cfg, args.batch, args.seq)
 
     with mesh:
         if args.chaos:
@@ -182,13 +213,10 @@ def main():
                       and summary["divergence_checks"] >= 1)
             raise SystemExit(0 if ok else 1)
 
-        params = jax.jit(lambda k: init_state(lm_init(k, cfg), opt),
-                         out_shardings=state_sh)(jax.random.PRNGKey(0))
+        params = init_sharded_state(cfg, opt, state_sh)
         step = make_train_step(cfg, tcfg, opt,
                                grad_shardings=state_sh["params"])
-        batch_abs = sp.train_batch_specs(cfg, args.batch, args.seq)
-        batch_sh = train_batch_shardings(mesh, batch_abs, args.batch)
-        pipe = DataPipeline(batch_fn, sharding=batch_sh, prefetch=1)
+        pipe = make_pipeline(cfg, mesh, args.batch, args.seq)
         hooks = {}
         if args.ckpt_dir:
             # the loop's own atomic checkpointing + crash-exact restart
@@ -204,6 +232,13 @@ def main():
               f"skipped={out['skipped']} rollbacks={out['rollbacks']} "
               f"resumed_from={out['resumed_from']}")
         pipe.close()
+    return out
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -260,7 +260,8 @@ def _streaming_sdpa(q, k, v, q_pos, k_pos, causal, window, softcap, chunk):
     s0 = jnp.zeros((b, g, rep, ql), jnp.float32)
     o0 = jnp.zeros((b, g, rep, ql, d), jnp.float32)
     from repro.distributed.context import scan_unroll
-    (m, s, o), _ = jax.lax.scan(step, (m0, s0, o0), jnp.arange(n_chunks),
+    (m, s, o), _ = jax.lax.scan(jax.checkpoint(step, prevent_cse=False),
+                                (m0, s0, o0), jnp.arange(n_chunks),
                                 unroll=scan_unroll(n_chunks))
     o = o / jnp.maximum(s, 1e-30)[..., None]
     # (b, g, rep, q, d) -> (b, q, h, d)

@@ -102,26 +102,37 @@ def _fused_core(kind: str, lr_fn, *, b1: float, b2: float, eps: float,
             lr = lr * lr_scale
 
         if use_kernel:
-            from repro.kernels.opt_step import fused_opt_step_leaf as leaf_fn
+            from repro.kernels.opt_step import (fused_opt_step_leaf as leaf_fn,
+                                                fused_opt_step_sharded)
         else:
             from repro.kernels.opt_step import opt_step_ref as leaf_fn
 
         pens = []
+        # the params' NamedShardings (make_train_step's grad_shardings): a
+        # Mosaic kernel cannot be partitioned by GSPMD, so on a mesh of
+        # more than one device each leaf's kernel runs per shard
+        shardings = extras.get("shardings")
 
-        def leaf(path, g, w, m, n):
+        def leaf(path, g, w, m, n, sh=None):
             leaf_lam = lam if (lam != 0.0 and policy.eligible(path, w)) else 0.0
-            new_w, new_m, new_n, pen = leaf_fn(
-                w, g, m, n, lr=lr, bc1=bc1, bc2=bc2, clip_scale=cscale,
-                lam=leaf_lam, fmt_name=fmt_name, block_size=block_size,
-                b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
-                core=kind, momentum=momentum, fisher_decay=fisher_decay,
-                ok=okf)
+            kw = dict(lr=lr, bc1=bc1, bc2=bc2, clip_scale=cscale,
+                      lam=leaf_lam, fmt_name=fmt_name, block_size=block_size,
+                      b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
+                      core=kind, momentum=momentum, fisher_decay=fisher_decay,
+                      ok=okf)
+            if use_kernel and sh is not None and sh.mesh.size > 1:
+                new_w, new_m, new_n, pen = fused_opt_step_sharded(
+                    w, g, m, n, sh, **kw)
+            else:
+                new_w, new_m, new_n, pen = leaf_fn(w, g, m, n, **kw)
             if leaf_lam != 0.0:
                 pens.append(pen.astype(jnp.float32))
             return (new_w, new_m, new_n)
 
-        out = jax.tree_util.tree_map_with_path(
-            leaf, grads, params, state["mu"], state["nu"])
+        trees = (grads, params, state["mu"], state["nu"])
+        if shardings is not None:
+            trees += (shardings,)
+        out = jax.tree_util.tree_map_with_path(leaf, *trees)
         new_params = jax.tree.map(lambda t: t[0], out,
                                   is_leaf=lambda t: isinstance(t, tuple))
         new_mu = jax.tree.map(lambda t: t[1], out,

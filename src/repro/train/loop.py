@@ -311,7 +311,8 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig, optimizer,
                                       jnp.all(jnp.isfinite(ce_ex)))
         updates, new_opt = tx.update(grads, state["opt"], params,
                                      fisher=fisher, step_ok=ok_loss,
-                                     lr_scale=state.get("lr_scale"))
+                                     lr_scale=state.get("lr_scale"),
+                                     shardings=grad_shardings)
 
         link = _link_metrics(new_opt)
         gnorm = link.get("gnorm")

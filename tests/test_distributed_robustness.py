@@ -20,6 +20,7 @@ from repro.data import DataPipeline, lm_batch, permutation_table
 from repro.distributed import (DEAD, AgreementError, Coordinator,
                                CoordinatorTimeout, InProcessBus, Straggle,
                                replica_divergence, tree_fingerprint)
+from repro.launch.mesh import make_mesh
 from repro.models.lm import LMConfig, lm_init
 from repro.optim import adamw, constant
 from repro.train import (InjectedCrash, TrainConfig, init_state,
@@ -440,7 +441,7 @@ def test_one_data_shard_nan_skips_step_on_all_shards():
     examples of data-shard 0: the step is skipped identically everywhere
     — params stay bit-identical on every device replica — and the
     replica audit finds no divergence."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     tcfg = _tcfg()
 
     base = make_loss_fn(CFG, tcfg)
@@ -485,7 +486,7 @@ def test_elastic_restore_across_mesh_shapes_under_corruption(tmp_path):
     restores bit-exactly onto 1x1 and 4x2 meshes; corrupting one shard
     of the newest save quarantines that WHOLE step first, so the elected
     restore target is the older complete set."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     sh2 = NamedSharding(mesh, P("data", "model"))
     sh1 = NamedSharding(mesh, P("model"))
 
@@ -507,7 +508,7 @@ def test_elastic_restore_across_mesh_shapes_under_corruption(tmp_path):
     template = jax.eval_shape(lambda: good)
     want = jax.device_get(good)
     for shape in ((1, 1), (4, 2)):
-        m2 = jax.make_mesh(shape, ("data", "model"))
+        m2 = make_mesh(shape, ("data", "model"))
         loaded, s = ckpt_io.load(str(tmp_path), template, step=7)
         assert s == 7
         placed = {

@@ -100,7 +100,7 @@ SUBPROC = textwrap.dedent("""
     def small_mesh(*, multi_pod=False):
         shape = (2, 2, 2) if multi_pod else (2, 4)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return mesh_mod.make_mesh(shape, axes)
 
     dr.make_production_mesh = small_mesh
     import repro.launch.specs as sp

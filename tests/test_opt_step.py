@@ -12,6 +12,7 @@ import pytest
 from repro.core import QuantConfig, QuantPolicy, get_format, quantize
 from repro.data import lm_batch, permutation_table
 from repro.kernels.opt_step import fused_opt_step_leaf, opt_step_ref
+from repro.launch.mesh import make_mesh
 from repro.models.lm import LMConfig, lm_init
 from repro.optim import (adamw, adamw_core, chain, constant,
                          fused_lotion_adamw_core)
@@ -341,7 +342,7 @@ def test_fused_state_shardings_mirror_params():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import state_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = {"blk/wq": jnp.zeros((64, 128)), "norm_scale": jnp.zeros((64,))}
     fused = fused_lotion_adamw_core(constant(1e-3), lam=10.0, policy=POLICY)
     state_abs = jax.eval_shape(lambda: init_state(params, fused))

@@ -18,6 +18,7 @@ from repro.core.quantize import dequantize_store, quantize_store
 from repro.distributed.sharding import _leaf_name, param_spec, params_shardings
 from repro.kernels.wq_matmul import wqt_matmul
 from repro.kernels.wq_matmul.ref import wqt_matmul_ref
+from repro.launch.mesh import make_mesh
 from repro.models.lm import LMConfig, lm_decode, lm_init, lm_prefill
 
 POLICY = QuantPolicy(min_size=256, include_embeddings=True)
@@ -247,7 +248,7 @@ def test_qtensor_sharding_specs_congruent():
           if n.endswith("/wq")]
     assert wq and tuple(wq[0])[-2:] == ("model", "data"), wq
     # placement smoke on a real mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = params_shardings(mesh, jax.eval_shape(lambda: qp))
     jax.device_put(qp, sh)
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.launch.mesh import make_mesh
 from repro.models.lm import (LMConfig, cache_insert, init_cache, lm_decode,
                              lm_init, lm_prefill)
 from repro.serve import Engine, Scheduler, SchedulerConfig, ServeConfig
@@ -276,7 +277,7 @@ def test_slot_pool_cache_shardings_cover_scheduler_pool():
 
     from repro.distributed.sharding import cache_shardings
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for kv in (False, "int8", "int4"):
         pool = jax.eval_shape(
             lambda kv=kv: init_cache(CFG, 4, 64, dtype=jnp.float32,

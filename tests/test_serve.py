@@ -211,11 +211,17 @@ def test_engine_per_request_budgets_and_eos():
     base = eng.generate([[1, 2, 3], [9, 8, 7]])
     ragged = eng.generate([[1, 2, 3], [9, 8, 7]], max_new_tokens=[3, 7])
     assert ragged == [base[0][:3], base[1][:7]]
-    eos = base[0][2]
+    # EOS = the first token whose FIRST occurrence in its row is at index
+    # >= 2 (greedy rows may repeat one token, so base[r][2] can occur
+    # earlier); that row stops there, the other row runs its full budget
+    row, cut, eos = next((r, i, t) for r, out in enumerate(base)
+                         for i, t in enumerate(out)
+                         if i >= 2 and t not in out[:i])
+    eos_ids = [eos if r == row else None for r in range(2)]
     stopped = eng.generate([[1, 2, 3], [9, 8, 7]], max_new_tokens=8,
-                           eos_id=[eos, None])
-    assert stopped[0] == base[0][:3] and stopped[0][-1] == eos
-    assert stopped[1] == base[1]
+                           eos_id=eos_ids)
+    assert stopped[row] == base[row][:cut + 1] and stopped[row][-1] == eos
+    assert stopped[1 - row] == base[1 - row]
     with pytest.raises(ValueError, match="entries"):
         eng.generate([[1]], max_new_tokens=[1, 2])
 
