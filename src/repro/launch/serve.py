@@ -30,6 +30,7 @@ exiting nonzero on any violation (DESIGN.md §10).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 
@@ -42,6 +43,7 @@ if os.environ.get("REPRO_FORCE_DEVICES"):
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro import spans  # noqa: E402
 from repro.configs import get_config, get_smoke_config  # noqa: E402
 from repro.core import (QuantPolicy, cast_params, get_format,  # noqa: E402
                         param_nbytes, quantize_params, qtensor_act_fmt,
@@ -97,7 +99,7 @@ def _replay(cfg, params, args, use_kernel, kv_quant, stored_bytes,
     print(f"continuous/static throughput: {rec['throughput_ratio']:.2f}x "
           f"(outputs identical: {rec['outputs_identical']})")
     live = {k: v for k, v in sch.counters.items() if v}
-    print(f"lifecycle counters: {live}")
+    print(f"lifecycle counters: {live} compiles {spans.compiles()}")
 
 
 def _chaos(cfg, params, args, use_kernel, kv_quant):
@@ -192,6 +194,9 @@ def parse_args(argv=None):
                          "stragglers, eviction storms, malformed/burst "
                          "submissions) with per-tick invariant audit")
     ap.add_argument("--chaos-seed", type=int, default=13)
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="capture a profiler trace of the run into DIR "
+                         "(README: Profiling)")
     return ap.parse_args(argv)
 
 
@@ -275,7 +280,9 @@ def run(args):
 def main(argv=None):
     args = parse_args(argv)
     enable_compile_cache()
-    run(args)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        run(args)
 
 
 if __name__ == "__main__":

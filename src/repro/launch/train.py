@@ -13,6 +13,7 @@ dry-run IS this launcher minus execution.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import math
 import os
 import tempfile
@@ -103,6 +104,9 @@ def parse_args(argv=None):
                          "instead of a plain run; exits nonzero on any "
                          "audit violation")
     ap.add_argument("--chaos-seed", type=int, default=1)
+    ap.add_argument("--profile", metavar="DIR", default=None,
+                    help="capture a profiler trace of the run into DIR "
+                         "(README: Profiling)")
     args = ap.parse_args(argv)
     if args.chaos and args.microbatches != 1:
         ap.error("--chaos requires --microbatches 1 (the poison scalar "
@@ -238,7 +242,9 @@ def run(args):
 def main(argv=None):
     args = parse_args(argv)
     enable_compile_cache()
-    run(args)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        run(args)
 
 
 if __name__ == "__main__":

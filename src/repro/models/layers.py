@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.qtensor import QTensor, kernel_enabled
 from repro.core.qtensor import matmul as _qt_matmul
 
@@ -335,14 +336,15 @@ def _cache_codes(c) -> Array:
 
 def _cache_write(cache, new, slot, bidx):
     """Write the (b, kvh, hd) vector `new` at ring slots."""
-    if _is_quantized_cache(cache):
-        bits = 4 if cache["codes"].dtype == jnp.uint8 else 8
-        q = kv_quantize(new[:, None], bits)  # (b,1,kvh,*)
-        return {
-            "codes": cache["codes"].at[bidx, slot].set(q["codes"][:, 0]),
-            "scale": cache["scale"].at[bidx, slot].set(q["scale"][:, 0]),
-        }
-    return cache.at[bidx, slot].set(new.astype(cache.dtype))
+    with jax.named_scope(spans.KV_CACHE):
+        if _is_quantized_cache(cache):
+            bits = 4 if cache["codes"].dtype == jnp.uint8 else 8
+            q = kv_quantize(new[:, None], bits)  # (b,1,kvh,*)
+            return {
+                "codes": cache["codes"].at[bidx, slot].set(q["codes"][:, 0]),
+                "scale": cache["scale"].at[bidx, slot].set(q["scale"][:, 0]),
+            }
+        return cache.at[bidx, slot].set(new.astype(cache.dtype))
 
 
 def attn_decode(
@@ -469,9 +471,10 @@ def paged_gather(cache, bt):
     to what the dense-ring cache of the same requests would hold.
     """
     def g(a):
-        out = a[bt]                              # (b, bps, bs, ...)
-        return out.reshape((out.shape[0], out.shape[1] * out.shape[2])
-                           + out.shape[3:])
+        with jax.named_scope(spans.KV_CACHE):
+            out = a[bt]                          # (b, bps, bs, ...)
+            return out.reshape((out.shape[0], out.shape[1] * out.shape[2])
+                               + out.shape[3:])
     if _is_quantized_cache(cache):
         return {"codes": g(cache["codes"]), "scale": g(cache["scale"])}
     return g(cache)
@@ -481,18 +484,18 @@ def _cache_write_paged(cache, new, bt, slot, block_size):
     """Write the (b, kvh, hd) vector ``new`` at ring slot ``slot`` of each
     row, routed through the block table into the pool.  Rows whose table
     entry is 0 (cleared/idle) land in the reserved dump block."""
-    b = new.shape[0]
-    bi = slot // block_size
-    off = slot % block_size
-    bid = jnp.take_along_axis(bt, bi[:, None], axis=1)[:, 0]
-    if _is_quantized_cache(cache):
-        bits = 4 if cache["codes"].dtype == jnp.uint8 else 8
-        q = kv_quantize(new[:, None], bits)  # (b,1,kvh,*)
-        return {
-            "codes": cache["codes"].at[bid, off].set(q["codes"][:, 0]),
-            "scale": cache["scale"].at[bid, off].set(q["scale"][:, 0]),
-        }
-    return cache.at[bid, off].set(new.astype(cache.dtype))
+    with jax.named_scope(spans.KV_CACHE):
+        bi = slot // block_size
+        off = slot % block_size
+        bid = jnp.take_along_axis(bt, bi[:, None], axis=1)[:, 0]
+        if _is_quantized_cache(cache):
+            bits = 4 if cache["codes"].dtype == jnp.uint8 else 8
+            q = kv_quantize(new[:, None], bits)  # (b,1,kvh,*)
+            return {
+                "codes": cache["codes"].at[bid, off].set(q["codes"][:, 0]),
+                "scale": cache["scale"].at[bid, off].set(q["scale"][:, 0]),
+            }
+        return cache.at[bid, off].set(new.astype(cache.dtype))
 
 
 def attn_decode_paged(
@@ -717,7 +720,9 @@ def attn_chunk_apply(
             [cache, jnp.zeros((b, 1) + cache.shape[2:], cache.dtype)], 1
         ).at[bidx, slots].set(vals.astype(cache.dtype))[:, :L]
 
-    return out, write(cache_k, k4), write(cache_v, v.reshape(b, cw, g, hd))
+    with jax.named_scope(spans.KV_CACHE):
+        return (out, write(cache_k, k4),
+                write(cache_v, v.reshape(b, cw, g, hd)))
 
 
 # --------------------------------------------------------------------------

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.qtensor import QTensor
 from repro.distributed.context import constrain, constrain_tree, scan_unroll
 
@@ -233,29 +234,31 @@ def _apply_block(p, cfg: LMConfig, kind: str, x: Array, positions: Array,
                  ctx: Optional[Array], attn_chunk: Optional[int]):
     aux = {}
     if kind in ATTN_KINDS:
-        h = _act_q(cfg, rms_norm(x, p["pre_norm_scale"]))
-        h = attn_apply(p["attn"], cfg.attn_spec(kind), h, positions,
-                       ctx=ctx if kind == "xattn" else None,
-                       chunk=attn_chunk)
-        if kind == "xattn":
-            h = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * h
-        if cfg.use_post_norm:
-            h = rms_norm(h, p["post_norm_scale"])
-        x = x + h
-        h = _act_q(cfg, rms_norm(x, p["ffn_norm_scale"]))
-        if cfg.ffn == "moe":
-            h_moe, aux = moe_apply(p["moe"], cfg.moe_spec(), h)
-            if cfg.n_shared_experts:
-                shared_spec = MLPSpec(cfg.d_model,
-                                      cfg.d_ff * cfg.n_shared_experts,
-                                      cfg.mlp_kind)
-                h_moe = h_moe + mlp_apply(p["shared_mlp"], shared_spec, h)
-            h = h_moe
-        else:
-            h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
-        if cfg.use_post_norm:
-            h = rms_norm(h, p["ffn_post_norm_scale"])
-        x = x + h
+        with jax.named_scope(spans.ATTN):
+            h = _act_q(cfg, rms_norm(x, p["pre_norm_scale"]))
+            h = attn_apply(p["attn"], cfg.attn_spec(kind), h, positions,
+                           ctx=ctx if kind == "xattn" else None,
+                           chunk=attn_chunk)
+            if kind == "xattn":
+                h = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * h
+            if cfg.use_post_norm:
+                h = rms_norm(h, p["post_norm_scale"])
+            x = x + h
+        with jax.named_scope(spans.MLP):
+            h = _act_q(cfg, rms_norm(x, p["ffn_norm_scale"]))
+            if cfg.ffn == "moe":
+                h_moe, aux = moe_apply(p["moe"], cfg.moe_spec(), h)
+                if cfg.n_shared_experts:
+                    shared_spec = MLPSpec(cfg.d_model,
+                                          cfg.d_ff * cfg.n_shared_experts,
+                                          cfg.mlp_kind)
+                    h_moe = h_moe + mlp_apply(p["shared_mlp"], shared_spec, h)
+                h = h_moe
+            else:
+                h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
+            if cfg.use_post_norm:
+                h = rms_norm(h, p["ffn_post_norm_scale"])
+            x = x + h
     elif kind == "mamba":
         h = rms_norm(x, p["pre_norm_scale"])
         x = x + mamba2_apply(p["mamba"], cfg.mamba_spec(), h)
@@ -278,48 +281,50 @@ def _apply_shared(p, cfg: LMConfig, x: Array, positions: Array,
 
 
 def _embed(params, cfg: LMConfig, tokens: Array) -> Array:
-    if cfg.n_codebooks > 1:
-        # tokens: (b, l, n_codebooks)
-        parts = [jnp.take(params["embed"][c], tokens[..., c], axis=0)
-                 for c in range(cfg.n_codebooks)]
-        x = sum(parts)
-    elif isinstance(params["embed"], QTensor):
-        # gather + per-row dequant: reads only the touched code rows
-        x = params["embed"].take(tokens)
-    else:
-        x = jnp.take(params["embed"], tokens, axis=0)
-    x = x.astype(cfg.dtype)
-    if cfg.emb_scale:
-        x = x * np.sqrt(cfg.d_model).astype(np.float32)
-    return x
+    with jax.named_scope(spans.EMBED):
+        if cfg.n_codebooks > 1:
+            # tokens: (b, l, n_codebooks)
+            parts = [jnp.take(params["embed"][c], tokens[..., c], axis=0)
+                     for c in range(cfg.n_codebooks)]
+            x = sum(parts)
+        elif isinstance(params["embed"], QTensor):
+            # gather + per-row dequant: reads only the touched code rows
+            x = params["embed"].take(tokens)
+        else:
+            x = jnp.take(params["embed"], tokens, axis=0)
+        x = x.astype(cfg.dtype)
+        if cfg.emb_scale:
+            x = x * np.sqrt(cfg.d_model).astype(np.float32)
+        return x
 
 
 def _head(params, cfg: LMConfig, x: Array) -> Array:
-    x = rms_norm(x, params["final_norm_scale"])
-    # gather the (small, model-sharded) d dim of the activations before the
-    # vocab matmul: keeps the contraction sharding aligned with the head
-    # weights, avoiding per-chunk multi-GB logits all-reduces (§Perf log).
-    x = constrain(x, "head_in")
-    if cfg.n_codebooks > 1:
-        if cfg.tie_embeddings:
-            logits = jnp.einsum("bld,cvd->blcv", x,
-                                params["embed"].astype(x.dtype))
+    with jax.named_scope(spans.HEAD):
+        x = rms_norm(x, params["final_norm_scale"])
+        # gather the (small, model-sharded) d dim of the activations before the
+        # vocab matmul: keeps the contraction sharding aligned with the head
+        # weights, avoiding per-chunk multi-GB logits all-reduces (§Perf log).
+        x = constrain(x, "head_in")
+        if cfg.n_codebooks > 1:
+            if cfg.tie_embeddings:
+                logits = jnp.einsum("bld,cvd->blcv", x,
+                                    params["embed"].astype(x.dtype))
+            else:
+                logits = jnp.einsum("bld,cdv->blcv", x,
+                                    params["lm_head"].astype(x.dtype))
         else:
-            logits = jnp.einsum("bld,cdv->blcv", x,
-                                params["lm_head"].astype(x.dtype))
-    else:
-        w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        if isinstance(w, QTensor):
-            # QTensor storage is out-major (vocab, d) for BOTH the tied
-            # table and the (transposed-at-pack-time) untied head — the
-            # transpose is baked into the layout, one kernel serves both
-            logits = matmul(x, w)
-        else:
-            logits = x @ (w.T if cfg.tie_embeddings else w).astype(x.dtype)
-    logits = constrain(logits.astype(jnp.float32), "logits")
-    if cfg.softcap_final is not None:
-        logits = cfg.softcap_final * jnp.tanh(logits / cfg.softcap_final)
-    return logits
+            w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+            if isinstance(w, QTensor):
+                # QTensor storage is out-major (vocab, d) for BOTH the tied
+                # table and the (transposed-at-pack-time) untied head — the
+                # transpose is baked into the layout, one kernel serves both
+                logits = matmul(x, w)
+            else:
+                logits = x @ (w.T if cfg.tie_embeddings else w).astype(x.dtype)
+        logits = constrain(logits.astype(jnp.float32), "logits")
+        if cfg.softcap_final is not None:
+            logits = cfg.softcap_final * jnp.tanh(logits / cfg.softcap_final)
+        return logits
 
 
 def _trunk(params, cfg: LMConfig, tokens: Array,
@@ -494,7 +499,8 @@ def cache_insert(pool_cache, row_cache, slot):
         return jax.lax.dynamic_update_index_in_dim(
             pool, jax.lax.squeeze(row, (1,)).astype(pool.dtype), slot, 1)
 
-    return jax.tree.map(one, pool_cache, row_cache)
+    with jax.named_scope(spans.KV_CACHE):
+        return jax.tree.map(one, pool_cache, row_cache)
 
 
 def cache_insert_paged(pool_cache, row_cache, table, write_mask):
@@ -510,7 +516,6 @@ def cache_insert_paged(pool_cache, row_cache, table, write_mask):
     in a dense ring row, because chunk i of the row IS ring slots
     ``[i*bs, (i+1)*bs)``.
     """
-    bids = jnp.where(write_mask, table, 0).astype(jnp.int32)
     bps = table.shape[0]
 
     def one(pool, row):
@@ -519,7 +524,9 @@ def cache_insert_paged(pool_cache, row_cache, table, write_mask):
         row = row.reshape((row.shape[0], bps, bs) + row.shape[2:])
         return pool.at[:, bids].set(row.astype(pool.dtype))
 
-    return jax.tree.map(one, pool_cache, row_cache)
+    with jax.named_scope(spans.KV_CACHE):
+        bids = jnp.where(write_mask, table, 0).astype(jnp.int32)
+        return jax.tree.map(one, pool_cache, row_cache)
 
 
 # ==========================================================================
@@ -643,32 +650,35 @@ def lm_prefill(params, cfg: LMConfig, tokens: Array,
             name = f"b{i}_{kind}"
             p = unit_p[name]
             if kind in ATTN_KINDS:
-                h = rms_norm(x, p["pre_norm_scale"])
-                o, (k, v) = attn_apply(
-                    p["attn"], cfg.attn_spec(kind), h, positions,
-                    ctx=ctx if kind == "xattn" else None,
-                    chunk=attn_chunk, return_kv=True)
-                new_caches[name] = _kv_to_cache(k, v, kind, cfg, cache_len,
-                                                kv_quant, pads=pads)
-                if kind == "xattn":
-                    o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
-                if cfg.use_post_norm:
-                    o = rms_norm(o, p["post_norm_scale"])
-                x = x + o
-                h = rms_norm(x, p["ffn_norm_scale"])
-                if cfg.ffn == "moe":
-                    hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h)
-                    if cfg.n_shared_experts:
-                        shared_spec = MLPSpec(cfg.d_model,
-                                              cfg.d_ff * cfg.n_shared_experts,
-                                              cfg.mlp_kind)
-                        hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
-                    h = hm
-                else:
-                    h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
-                if cfg.use_post_norm:
-                    h = rms_norm(h, p["ffn_post_norm_scale"])
-                x = x + h
+                with jax.named_scope(spans.ATTN):
+                    h = rms_norm(x, p["pre_norm_scale"])
+                    o, (k, v) = attn_apply(
+                        p["attn"], cfg.attn_spec(kind), h, positions,
+                        ctx=ctx if kind == "xattn" else None,
+                        chunk=attn_chunk, return_kv=True)
+                    with jax.named_scope(spans.KV_CACHE):
+                        new_caches[name] = _kv_to_cache(
+                            k, v, kind, cfg, cache_len, kv_quant, pads=pads)
+                    if kind == "xattn":
+                        o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
+                    if cfg.use_post_norm:
+                        o = rms_norm(o, p["post_norm_scale"])
+                    x = x + o
+                with jax.named_scope(spans.MLP):
+                    h = rms_norm(x, p["ffn_norm_scale"])
+                    if cfg.ffn == "moe":
+                        hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h)
+                        if cfg.n_shared_experts:
+                            shared_spec = MLPSpec(cfg.d_model,
+                                                  cfg.d_ff * cfg.n_shared_experts,
+                                                  cfg.mlp_kind)
+                            hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
+                        h = hm
+                    else:
+                        h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
+                    if cfg.use_post_norm:
+                        h = rms_norm(h, p["ffn_post_norm_scale"])
+                    x = x + h
             elif kind == "mamba":
                 h = rms_norm(x, p["pre_norm_scale"])
                 o, st = mamba2_apply(p["mamba"], cfg.mamba_spec(), h,
@@ -729,8 +739,9 @@ def quantize_cache(cfg: LMConfig, cache, kv_quant):
         return cache
 
     def q(leaf):
-        return {"k": layers.kv_quantize(leaf["k"], bits),
-                "v": layers.kv_quantize(leaf["v"], bits)}
+        with jax.named_scope(spans.KV_CACHE):
+            return {"k": layers.kv_quantize(leaf["k"], bits),
+                    "v": layers.kv_quantize(leaf["v"], bits)}
 
     unit = dict(cache["unit"])
     for i, kind in enumerate(cfg.pattern):
@@ -775,30 +786,32 @@ def lm_prefill_chunk(params, cfg: LMConfig, cache, tokens: Array,
             name = f"b{i}_{kind}"
             p = unit_p[name]
             if kind in ATTN_KINDS:
-                h = rms_norm(x, p["pre_norm_scale"])
-                o, ck, cv = layers.attn_chunk_apply(
-                    p["attn"], cfg.attn_spec(kind), h, positions,
-                    chunk_lens, unit_c[name]["k"], unit_c[name]["v"])
-                if kind == "xattn":
-                    o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
-                new_c[name] = {"k": ck, "v": cv}
-                if cfg.use_post_norm:
-                    o = rms_norm(o, p["post_norm_scale"])
-                x = x + o
-                h = rms_norm(x, p["ffn_norm_scale"])
-                if cfg.ffn == "moe":
-                    hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h)
-                    if cfg.n_shared_experts:
-                        shared_spec = MLPSpec(cfg.d_model,
-                                              cfg.d_ff * cfg.n_shared_experts,
-                                              cfg.mlp_kind)
-                        hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
-                    h = hm
-                else:
-                    h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
-                if cfg.use_post_norm:
-                    h = rms_norm(h, p["ffn_post_norm_scale"])
-                x = x + h
+                with jax.named_scope(spans.ATTN):
+                    h = rms_norm(x, p["pre_norm_scale"])
+                    o, ck, cv = layers.attn_chunk_apply(
+                        p["attn"], cfg.attn_spec(kind), h, positions,
+                        chunk_lens, unit_c[name]["k"], unit_c[name]["v"])
+                    if kind == "xattn":
+                        o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
+                    new_c[name] = {"k": ck, "v": cv}
+                    if cfg.use_post_norm:
+                        o = rms_norm(o, p["post_norm_scale"])
+                    x = x + o
+                with jax.named_scope(spans.MLP):
+                    h = rms_norm(x, p["ffn_norm_scale"])
+                    if cfg.ffn == "moe":
+                        hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h)
+                        if cfg.n_shared_experts:
+                            shared_spec = MLPSpec(cfg.d_model,
+                                                  cfg.d_ff * cfg.n_shared_experts,
+                                                  cfg.mlp_kind)
+                            hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
+                        h = hm
+                    else:
+                        h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
+                    if cfg.use_post_norm:
+                        h = rms_norm(h, p["ffn_post_norm_scale"])
+                    x = x + h
             else:
                 raise NotImplementedError(
                     f"chunked prefill needs attention-family blocks; "
@@ -824,14 +837,16 @@ def lm_prefill_chunk(params, cfg: LMConfig, cache, tokens: Array,
     # per repeat instead of double-buffered as stacked scan ys
     def carry_body(carry, unit_p):
         x, full_cache, r = carry
-        unit_c = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
-            full_cache)
+        with jax.named_scope(spans.KV_CACHE):
+            unit_c = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
+                full_cache)
         x, new_c = unit_body(x, (unit_p, unit_c))
-        full_cache = jax.tree.map(
-            lambda full, upd: jax.lax.dynamic_update_index_in_dim(
-                full, upd.astype(full.dtype), r, 0),
-            full_cache, new_c)
+        with jax.named_scope(spans.KV_CACHE):
+            full_cache = jax.tree.map(
+                lambda full, upd: jax.lax.dynamic_update_index_in_dim(
+                    full, upd.astype(full.dtype), r, 0),
+                full_cache, new_c)
         return (x, full_cache, r + 1), None
 
     (x, new_stacked, _), _ = jax.lax.scan(
@@ -874,43 +889,45 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
             name = f"b{i}_{kind}"
             p = unit_p[name]
             if kind in ATTN_KINDS:
-                h = rms_norm(x, p["pre_norm_scale"])
-                spec = cfg.attn_spec(kind)
-                cross_kv = None
-                if kind == "xattn":
-                    cross_kv = (unit_c[name]["k"].astype(x.dtype),
-                                unit_c[name]["v"].astype(x.dtype))
-                if block_tables is not None:
-                    o, ck, cv = attn_decode_paged(
-                        p["attn"], spec, h, pos,
-                        unit_c[name]["k"], unit_c[name]["v"],
-                        block_tables, block_size)
-                else:
-                    o, ck, cv = attn_decode(p["attn"], spec, h, pos,
-                                            unit_c[name]["k"],
-                                            unit_c[name]["v"],
-                                            cross_kv=cross_kv)
-                if kind == "xattn":
-                    o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
-                new_c[name] = {"k": ck, "v": cv}
-                if cfg.use_post_norm:
-                    o = rms_norm(o, p["post_norm_scale"])
-                x = x + o
-                h = rms_norm(x, p["ffn_norm_scale"])
-                if cfg.ffn == "moe":
-                    hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h,
-                                      token_mask=token_mask)
-                    if cfg.n_shared_experts:
-                        shared_spec = MLPSpec(cfg.d_model,
-                                              cfg.d_ff * cfg.n_shared_experts,
-                                              cfg.mlp_kind)
-                        hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
-                    h = hm
-                else:
-                    h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
-                if cfg.use_post_norm:
-                    h = rms_norm(h, p["ffn_post_norm_scale"])
-                x = x + h
+                with jax.named_scope(spans.ATTN):
+                    h = rms_norm(x, p["pre_norm_scale"])
+                    spec = cfg.attn_spec(kind)
+                    cross_kv = None
+                    if kind == "xattn":
+                        cross_kv = (unit_c[name]["k"].astype(x.dtype),
+                                    unit_c[name]["v"].astype(x.dtype))
+                    if block_tables is not None:
+                        o, ck, cv = attn_decode_paged(
+                            p["attn"], spec, h, pos,
+                            unit_c[name]["k"], unit_c[name]["v"],
+                            block_tables, block_size)
+                    else:
+                        o, ck, cv = attn_decode(p["attn"], spec, h, pos,
+                                                unit_c[name]["k"],
+                                                unit_c[name]["v"],
+                                                cross_kv=cross_kv)
+                    if kind == "xattn":
+                        o = jnp.tanh(p["xattn_gate"]).astype(x.dtype) * o
+                    new_c[name] = {"k": ck, "v": cv}
+                    if cfg.use_post_norm:
+                        o = rms_norm(o, p["post_norm_scale"])
+                    x = x + o
+                with jax.named_scope(spans.MLP):
+                    h = rms_norm(x, p["ffn_norm_scale"])
+                    if cfg.ffn == "moe":
+                        hm, _ = moe_apply(p["moe"], cfg.moe_spec(), h,
+                                          token_mask=token_mask)
+                        if cfg.n_shared_experts:
+                            shared_spec = MLPSpec(cfg.d_model,
+                                                  cfg.d_ff * cfg.n_shared_experts,
+                                                  cfg.mlp_kind)
+                            hm = hm + mlp_apply(p["shared_mlp"], shared_spec, h)
+                        h = hm
+                    else:
+                        h = mlp_apply(p["mlp"], cfg.mlp_spec(), h)
+                    if cfg.use_post_norm:
+                        h = rms_norm(h, p["ffn_post_norm_scale"])
+                    x = x + h
             elif kind == "mamba":
                 h = rms_norm(x, p["pre_norm_scale"])
                 o, st = mamba2_decode(p["mamba"], cfg.mamba_spec(), h,
@@ -957,14 +974,16 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
     # carry aliases (§Perf log: 30.9 -> ~10 GB/dev on 32k x 128 decode).
     def carry_body(carry, unit_p):
         x, full_cache, r = carry
-        unit_c = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
-            full_cache)
+        with jax.named_scope(spans.KV_CACHE):
+            unit_c = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),
+                full_cache)
         x, new_c = unit_body(x, (unit_p, unit_c))
-        full_cache = jax.tree.map(
-            lambda full, upd: jax.lax.dynamic_update_index_in_dim(
-                full, upd.astype(full.dtype), r, 0),
-            full_cache, new_c)
+        with jax.named_scope(spans.KV_CACHE):
+            full_cache = jax.tree.map(
+                lambda full, upd: jax.lax.dynamic_update_index_in_dim(
+                    full, upd.astype(full.dtype), r, 0),
+                full_cache, new_c)
         return (x, full_cache, r + 1), None
 
     (x, new_stacked, _), _ = jax.lax.scan(

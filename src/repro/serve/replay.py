@@ -177,19 +177,18 @@ def replay_continuous(scheduler, workload: List[ReplayRequest]) -> dict:
     start_computed = scheduler.prefill_tokens_computed
     start_skipped = scheduler.prefill_tokens_skipped
     done_at: Dict[int, float] = {}
-    # per-step stall capture: scheduler.stall_log is a bounded deque (a
-    # long-lived server must not grow host memory), so the replay keeps
-    # its own complete list by reading the newest entry after each step
+    # per-step stall: the prefill tokens each step computed
     stall_ticks: List[int] = []
     while scheduler.has_work():
         if not scheduler.pool.occupied():
             # idle: jump to the next arrival still in the queue
             nxt = min(scheduler.requests[r].arrival for r in scheduler.queue)
             clock = max(clock, nxt)
+        computed = scheduler.prefill_tokens_computed
         t0 = time.perf_counter()
         completed = scheduler.step(now=clock)
         clock += time.perf_counter() - t0
-        stall_ticks.append(scheduler.stall_log[-1])
+        stall_ticks.append(scheduler.prefill_tokens_computed - computed)
         for req in completed:
             done_at[rid_of[req.rid]] = clock
     outputs = {rid_of[r]: scheduler.requests[r].out for r in rid_of}

@@ -75,6 +75,12 @@ resolves; overload degrades instead of collapsing:
   bugs degrade to slow-but-correct.  FAILED only if the fallback also
   faults.
 
+Each ``submit`` and ``step``, and each phase of a step (expiry,
+admission, the prefill chunk, its finish, the tick dispatch, the sync on
+its tokens, retirement), is a host span of :mod:`repro.spans`, so a
+profiler trace puts the device's idle time down to what the host was
+doing; the jitted programs' ops carry the model's named scopes.
+
 Greedy generations are token-identical to the static engine for the same
 request set (the engine's per-row ``prompt_lens`` masking makes static
 batching pad-invariant; capacity-based MoE routing is the documented
@@ -93,6 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.qtensor import qtensor_act_fmt, qtensor_use_kernel
 from repro.models.lm import (LMConfig, cache_insert, cache_insert_paged,
                              init_cache, lm_decode, lm_prefill,
@@ -105,10 +112,6 @@ from .prefix_cache import PrefixCache
 from .slots import (COMPLETED, DECODING, FAILED, PREEMPTED, PREFILLING,
                     QUEUED, REJECTED, TIMED_OUT, RejectedError, Request,
                     SlotPool, request_problem)
-
-# host-memory bound on the per-step accounting logs of a long-lived
-# server (a few ticks/second for days would otherwise grow without limit)
-STALL_LOG_MAXLEN = 4096
 
 COUNTER_KEYS = ("submitted", "admitted", "completed", "timed_out",
                 "rejected", "shed", "preempted", "resumed", "failed",
@@ -233,17 +236,12 @@ class Scheduler:
         self.splice_host_transfers = 0
         self.prefix_blocks_shared = 0
         # chunked-prefill / prefix-cache accounting (ISSUE 5): prefill
-        # tokens computed per step() (the decode-stall signal — bounded
-        # by prefill_chunk when chunking is on, by the longest prompt
-        # when it is not) and tokens skipped via prefix-cache splices.
-        # Bounded: a long-lived server steps forever, so the log keeps
-        # only the most recent STALL_LOG_MAXLEN entries (consumers that
-        # need every entry — replay — read stall_log[-1] after each step)
-        self.stall_log: collections.deque = collections.deque(
-            maxlen=STALL_LOG_MAXLEN)
+        # tokens computed (its per-step delta is the decode-stall signal
+        # — bounded by prefill_chunk when chunking is on, by the longest
+        # prompt when it is not) and tokens skipped via prefix-cache
+        # splices
         self.prefill_tokens_computed = 0
         self.prefill_tokens_skipped = 0
-        self._stall_tokens = 0
 
         self._chunked = sched.prefill_chunk is not None
         if self._chunked:
@@ -334,7 +332,8 @@ class Scheduler:
         }
 
         def _sample(logits, key):
-            return sample_token(logits, key, scfg.temperature)
+            with jax.named_scope(spans.SAMPLE):
+                return sample_token(logits, key, scfg.temperature)
 
         def _prefill_fn(p, toks, lens, key):
             with qtensor_use_kernel(scfg.use_kernel), \
@@ -522,48 +521,50 @@ class Scheduler:
         ``"queue_full"``, and a deadline that the current backlog already
         makes unmeetable is shed with ``"slo_shed"`` (``slo_shed=True``).
         """
-        mnt = (max_new_tokens if max_new_tokens is not None
-               else self.scfg.max_new_tokens)
-        self.counters["submitted"] += 1
-        problem = request_problem(prompt, mnt, self.sched.cache_len,
-                                  self.cfg.vocab)
-        if problem is not None:
-            reason, msg = problem
-            if strict:
-                # the submission never happened: raise without recording
-                self.counters["submitted"] -= 1
-                raise RejectedError(reason, msg)
-            self.counters["rejected"] += 1
-            return self._terminal_submission(prompt, mnt, eos_id, arrival,
-                                             REJECTED, reason)
-        if len(self.queue) >= self.sched.max_queue:
-            # bounded queue: shed at the door instead of queueing forever
-            if strict:
-                self.counters["submitted"] -= 1
-                raise RejectedError(
-                    "queue_full",
-                    f"submit queue at max_queue={self.sched.max_queue}")
-            self.counters["rejected"] += 1
-            return self._terminal_submission(prompt, mnt, eos_id, arrival,
-                                             REJECTED, "queue_full")
-        if (deadline is not None and self.sched.slo_shed
-                and self._deadline_unmeetable(prompt, mnt, arrival,
-                                              deadline)):
-            self.counters["shed"] += 1
-            return self._terminal_submission(prompt, mnt, eos_id, arrival,
-                                             REJECTED, "slo_shed")
-        rid = self._next_rid
-        self._next_rid += 1
-        req = Request(rid=rid, prompt=list(prompt), max_new_tokens=mnt,
-                      eos_id=eos_id, arrival=arrival, deadline=deadline,
-                      priority=priority)
-        self.requests[rid] = req
-        if mnt <= 0:
-            req.transition(COMPLETED, "empty_budget")
-            self.counters["completed"] += 1
-        else:
-            self.queue.append(rid)
-        return rid
+        # every path that returns a rid returns this one
+        with spans.span(spans.SERVE_SUBMIT, rid=self._next_rid):
+            mnt = (max_new_tokens if max_new_tokens is not None
+                   else self.scfg.max_new_tokens)
+            self.counters["submitted"] += 1
+            problem = request_problem(prompt, mnt, self.sched.cache_len,
+                                      self.cfg.vocab)
+            if problem is not None:
+                reason, msg = problem
+                if strict:
+                    # the submission never happened: raise without recording
+                    self.counters["submitted"] -= 1
+                    raise RejectedError(reason, msg)
+                self.counters["rejected"] += 1
+                return self._terminal_submission(prompt, mnt, eos_id, arrival,
+                                                 REJECTED, reason)
+            if len(self.queue) >= self.sched.max_queue:
+                # bounded queue: shed at the door instead of queueing forever
+                if strict:
+                    self.counters["submitted"] -= 1
+                    raise RejectedError(
+                        "queue_full",
+                        f"submit queue at max_queue={self.sched.max_queue}")
+                self.counters["rejected"] += 1
+                return self._terminal_submission(prompt, mnt, eos_id, arrival,
+                                                 REJECTED, "queue_full")
+            if (deadline is not None and self.sched.slo_shed
+                    and self._deadline_unmeetable(prompt, mnt, arrival,
+                                                  deadline)):
+                self.counters["shed"] += 1
+                return self._terminal_submission(prompt, mnt, eos_id, arrival,
+                                                 REJECTED, "slo_shed")
+            rid = self._next_rid
+            self._next_rid += 1
+            req = Request(rid=rid, prompt=list(prompt), max_new_tokens=mnt,
+                          eos_id=eos_id, arrival=arrival, deadline=deadline,
+                          priority=priority)
+            self.requests[rid] = req
+            if mnt <= 0:
+                req.transition(COMPLETED, "empty_budget")
+                self.counters["completed"] += 1
+            else:
+                self.queue.append(rid)
+            return rid
 
     def _terminal_submission(self, prompt, mnt, eos_id, arrival,
                              state: str, reason: str) -> int:
@@ -586,14 +587,19 @@ class Scheduler:
         advance at most one prefill chunk (chunked mode), run one decode
         tick, retire finished slots.  Returns every request that reached
         a terminal state during this step."""
-        self._stall_tokens = 0
-        self._update_rate_estimate(now)
-        terminal = self._expire(now) if now is not None else []
-        terminal += self._admit(now)
-        if self._chunked:
-            terminal += self._prefill_tick()
-        terminal += self._do_tick()
-        self.stall_log.append(self._stall_tokens)
+        with spans.span(spans.SERVE_STEP):
+            self._update_rate_estimate(now)
+            terminal = []
+            if now is not None:
+                with spans.span(spans.SERVE_EXPIRE):
+                    terminal += self._expire(now)
+            with spans.span(spans.SERVE_ADMIT) as sp:
+                admitted = self.counters["admitted"]
+                terminal += self._admit(now)
+                sp.set_metadata(n=self.counters["admitted"] - admitted)
+            if self._chunked:
+                terminal += self._prefill_tick()
+            terminal += self._do_tick()
         return terminal
 
     def run(self) -> Dict[int, List[int]]:
@@ -1018,7 +1024,6 @@ class Scheduler:
                 self._reattach_blocks(req)     # zero-recompute resume
                 continue
             seq = req.resume_tokens()
-            self._stall_tokens += len(seq)
             self.prefill_tokens_computed += len(seq)
 
             toks = np.asarray([seq], np.int32)
@@ -1050,7 +1055,8 @@ class Scheduler:
                         req.out[-1], len(seq), req.max_new_tokens, eos,
                         len(req.out))
                 continue
-            first = int(tok[0])
+            with spans.span(spans.SERVE_SYNC):
+                first = int(tok[0])
             req.out.append(first)
             self._emitted_tokens += 1
             if req.finished_by(first, 1):
@@ -1168,31 +1174,41 @@ class Scheduler:
         rid = self._prefill_q[0]
         job = self._prefills[rid]
         req = self.requests[rid]
-        if job.cache is None:
-            self._start_prefill(req, job)
         cw = self.sched.prefill_chunk
         n = len(job.seq)
-        take = min(cw, n - job.next)
-        toks = np.zeros((1, cw), np.int32)
-        toks[0, :take] = job.seq[job.next:job.next + take]
-        key = jax.random.fold_in(self._key, rid)
-        self.n_prefills += 1
-        req.prefill_chunks += 1
-        tok, job.cache = self._chunk(
-            self.params, job.cache, jnp.asarray(toks),
-            jnp.asarray([job.next], jnp.int32),
-            jnp.asarray([take], jnp.int32), key)
-        job.next += take
-        self._stall_tokens += take
-        self.prefill_tokens_computed += take
+        with spans.span(spans.SERVE_PREFILL_CHUNK, rid=rid) as sp:
+            if job.cache is None:
+                self._start_prefill(req, job)
+            take = min(cw, n - job.next)
+            sp.set_metadata(tokens=take, start=job.next)
+            toks = np.zeros((1, cw), np.int32)
+            toks[0, :take] = job.seq[job.next:job.next + take]
+            key = jax.random.fold_in(self._key, rid)
+            self.n_prefills += 1
+            req.prefill_chunks += 1
+            tok, job.cache = self._chunk(
+                self.params, job.cache, jnp.asarray(toks),
+                jnp.asarray([job.next], jnp.int32),
+                jnp.asarray([take], jnp.int32), key)
+            job.next += take
+            self.prefill_tokens_computed += take
         if job.next < n:
             return []
 
         # final chunk: the request leaves PREFILLING
-        self._prefill_q.popleft()
-        del self._prefills[rid]
-        if self.paged:
-            return self._finish_prefill_paged(req, job, n, tok)
+        with spans.span(spans.SERVE_PREFILL_FINISH, rid=rid):
+            self._prefill_q.popleft()
+            del self._prefills[rid]
+            if self.paged:
+                return self._finish_prefill_paged(req, job, n, tok)
+            return self._finish_prefill_dense(req, job, n, tok)
+
+    def _finish_prefill_dense(self, req: Request, job: _PrefillJob, n: int,
+                              tok) -> List[Request]:
+        """Dense-pool final chunk: publish full chunks to the prefix
+        trie, take the first token (resumes keep their in-flight one) and
+        insert the quantized row at the request's slot."""
+        cw = self.sched.prefill_chunk
         if self.prefix is not None:
             self._publish_blocks(job.seq, job.cache, n // cw)
             self.prefix.release(job.pinned)
@@ -1205,7 +1221,8 @@ class Scheduler:
                 self._cache, self._state, job.cache, req.slot, req.out[-1],
                 n, req.max_new_tokens, eos, len(req.out))
             return []
-        first = int(tok[0])
+        with spans.span(spans.SERVE_SYNC):
+            first = int(tok[0])
         req.out.append(first)
         self._emitted_tokens += 1
         if req.finished_by(first, 1):
@@ -1232,7 +1249,8 @@ class Scheduler:
         k_full = n // cw
         eos = -1 if req.eos_id is None else req.eos_id
         if not req.out:
-            first = int(tok[0])
+            with spans.span(spans.SERVE_SYNC):
+                first = int(tok[0])
             req.out.append(first)
             self._emitted_tokens += 1
             if req.finished_by(first, 1):
@@ -1310,19 +1328,28 @@ class Scheduler:
             self._inject_bad_slots.clear()
             return []
         self.n_ticks += 1
-        key = jax.random.fold_in(self._tick_key, self.n_ticks)
-        if self.paged:
-            if self._tables_dirty:
-                self._tables = jnp.asarray(self._tables_host)
-                self._tables_dirty = False
-            self._pool_cache, self._state, em, bad = self._tick(
-                self.params, self._pool_cache, self._tables, self._state,
-                key)
-        else:
-            self._cache, self._state, em, bad = self._tick(
-                self.params, self._cache, self._state, key)
-        em, bad = jax.device_get((em, bad))  # ONE sync per tick: (k, n)
-        em, bad = np.asarray(em), np.asarray(bad)
+        with spans.span(spans.SERVE_DECODE_TICK, slots=len(occupied)):
+            key = jax.random.fold_in(self._tick_key, self.n_ticks)
+            if self.paged:
+                if self._tables_dirty:
+                    self._tables = jnp.asarray(self._tables_host)
+                    self._tables_dirty = False
+                self._pool_cache, self._state, em, bad = self._tick(
+                    self.params, self._pool_cache, self._tables, self._state,
+                    key)
+            else:
+                self._cache, self._state, em, bad = self._tick(
+                    self.params, self._cache, self._state, key)
+        with spans.span(spans.SERVE_SYNC):
+            em, bad = jax.device_get((em, bad))  # ONE sync per tick: (k, n)
+        with spans.span(spans.SERVE_RETIRE):
+            return self._retire(occupied, np.asarray(em), np.asarray(bad))
+
+    def _retire(self, occupied, em: np.ndarray, bad: np.ndarray
+                ) -> List[Request]:
+        """Host side of a tick: append each slot's emitted tokens (the
+        (k, n_slots) block), apply the device's termination rule, free
+        finished slots and quarantine poisoned ones."""
         injected = self._inject_bad_slots
         self._inject_bad_slots = set()
         terminal = []
@@ -1330,7 +1357,8 @@ class Scheduler:
             req = self.requests[rid]
             req.ticks += 1
             if bad[:, slot].any() or slot in injected:
-                terminal += self._quarantine(req, slot)
+                with spans.span(spans.SERVE_QUARANTINE, rid=rid):
+                    terminal += self._quarantine(req, slot)
                 continue
             for s in range(self.sched.steps_per_tick):
                 t = int(em[s, slot])

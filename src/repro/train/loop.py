@@ -14,6 +14,10 @@ microbatch scan) and runs the chain.  With the default
 closed-form gradient exactly once per step — outside the microbatch scan
 and outside clipping (DESIGN.md §2); ``penalty_placement="loss"`` keeps
 the seed-era loss-side behavior.
+
+The step's ops sit under the named scopes ``forward_backward`` and
+``update``, and each :func:`run_loop` step is a step marker holding host
+spans for its data fetch, dispatch and sync (:mod:`repro.spans`).
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import QuantConfig, cast_params, forward_params, penalty
 from repro.models.lm import LMConfig, lm_forward
 from repro.optim import (UpdateTransform, as_transform, apply_updates, chain,
@@ -267,7 +272,8 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig, optimizer,
 
         if tcfg.n_microbatches > 1:
             def micro(c, mb):
-                (l, aux), g = grad_fn(params, mb, fisher, rng)
+                with jax.named_scope(spans.FORWARD_BACKWARD):
+                    (l, aux), g = grad_fn(params, mb, fisher, rng)
                 acc_l, acc_g = c
                 return (acc_l + l, jax.tree.map(jnp.add, acc_g, g)), aux
 
@@ -282,7 +288,8 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig, optimizer,
             ce_ex = auxs.pop("ce_ex", None)  # (n, b/n) — keep raw terms
             aux = jax.tree.map(lambda a: a.mean(), auxs)
         else:
-            (loss, aux), grads = grad_fn(params, batch, fisher, rng)
+            with jax.named_scope(spans.FORWARD_BACKWARD):
+                (loss, aux), grads = grad_fn(params, batch, fisher, rng)
             aux = dict(aux)
             ce_ex = aux.pop("ce_ex", None)
 
@@ -309,34 +316,35 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig, optimizer,
         if ce_ex is not None:
             ok_loss = jnp.logical_and(ok_loss,
                                       jnp.all(jnp.isfinite(ce_ex)))
-        updates, new_opt = tx.update(grads, state["opt"], params,
-                                     fisher=fisher, step_ok=ok_loss,
-                                     lr_scale=state.get("lr_scale"),
-                                     shardings=grad_shardings)
+        with jax.named_scope(spans.UPDATE):
+            updates, new_opt = tx.update(grads, state["opt"], params,
+                                         fisher=fisher, step_ok=ok_loss,
+                                         lr_scale=state.get("lr_scale"),
+                                         shardings=grad_shardings)
 
-        link = _link_metrics(new_opt)
-        gnorm = link.get("gnorm")
-        if gnorm is None:
-            gnorm = global_norm(grads)
-        ok = jnp.logical_and(ok_loss, jnp.isfinite(gnorm))
+            link = _link_metrics(new_opt)
+            gnorm = link.get("gnorm")
+            if gnorm is None:
+                gnorm = global_norm(grads)
+            ok = jnp.logical_and(ok_loss, jnp.isfinite(gnorm))
 
-        if tx.applies_updates:
-            # a fused terminal core emits new params straight from the
-            # step kernel (update already SC_OK-gated inside it; adding a
-            # tree-wide select here would re-introduce the extra HBM pass
-            # the fusion removed)
-            new_params, gated_opt = updates, new_opt
-        else:
-            # jnp chain: select per leaf — a skipped step keeps params
-            # AND the whole chain state (moments, count, EF error) so the
-            # replayed schedule is bit-identical to never having seen the
-            # poisoned batch
-            def sel(new, old):
-                return jnp.where(ok, new, old)
+            if tx.applies_updates:
+                # a fused terminal core emits new params straight from the
+                # step kernel (update already SC_OK-gated inside it; adding a
+                # tree-wide select here would re-introduce the extra HBM pass
+                # the fusion removed)
+                new_params, gated_opt = updates, new_opt
+            else:
+                # jnp chain: select per leaf — a skipped step keeps params
+                # AND the whole chain state (moments, count, EF error) so the
+                # replayed schedule is bit-identical to never having seen the
+                # poisoned batch
+                def sel(new, old):
+                    return jnp.where(ok, new, old)
 
-            new_params = jax.tree.map(sel, apply_updates(params, updates),
-                                      params)
-            gated_opt = jax.tree.map(sel, new_opt, state["opt"])
+                new_params = jax.tree.map(sel, apply_updates(params, updates),
+                                          params)
+                gated_opt = jax.tree.map(sel, new_opt, state["opt"])
 
         new_state = dict(state)
         new_state.update(params=new_params, opt=gated_opt,
@@ -599,74 +607,85 @@ def run_loop(train_step, state, pipeline, n_steps: int,
             f"lr_scale={lr_scale_now:g} for {cooldown_steps} steps")
 
     while cur < n_steps:
-        batch = next(pipeline)
-        t0 = time.perf_counter()
-        state, metrics = step_jit(state, batch)
-        # the loss transfer doubles as the step sync; the guard flag
-        # rides the same transfer
-        loss_v = float(metrics["loss"])
-        dt = time.perf_counter() - t0
-        times.append(dt)
-        cur += 1
-        skipped = bool(metrics["skipped"]) if "skipped" in metrics else False
+        with spans.step(spans.TRAIN_STEP, cur):
+            with spans.span(spans.TRAIN_DATA):
+                batch = next(pipeline)
+            t0 = time.perf_counter()
+            with spans.span(spans.TRAIN_DISPATCH):
+                state, metrics = step_jit(state, batch)
+            # the loss transfer doubles as the step sync; the guard flag
+            # rides the same transfer
+            with spans.span(spans.TRAIN_SYNC):
+                loss_v = float(metrics["loss"])
+            dt = time.perf_counter() - t0
+            times.append(dt)
+            cur += 1
+            skipped = bool(metrics["skipped"]) if "skipped" in metrics else False
 
-        if skipped:
-            counters["skipped"] += 1
-            consec_skips += 1
-            if consec_skips > max_skips:
-                diag = {"step": cur, "loss": loss_v,
-                        "grad_norm": float(metrics["grad_norm"]),
-                        **{k: v for k, v in counters.items()}}
-                raise NonFiniteBudgetError(
-                    f"{consec_skips} consecutive non-finite steps "
-                    f"(budget {max_skips}) at step {cur}: loss={loss_v}, "
-                    f"gnorm={diag['grad_norm']} — data or optimizer state "
-                    f"is persistently poisoned", diag)
-        else:
-            consec_skips = 0
-            if monitor is not None and monitor.observe(loss_v):
-                do_rollback("loss", loss_v, "rollbacks")
-                continue
-
-        if cooldown > 0:
-            cooldown -= 1
-            if cooldown == 0 and lr_scale_now != 1.0:
-                lr_scale_now = 1.0
-                state = dict(state)
-                state["lr_scale"] = jnp.ones((), jnp.float32)
-                log(f"run_loop: cooldown over at step {cur}, lr restored")
-
-        if step_hook is not None:
-            step_hook(state, metrics)
-
-        step = cur
-        if log_every and step % log_every == 0:
-            window = np.asarray(times)
-            p50, p95 = (np.percentile(window, 50),
-                        np.percentile(window, straggler_pct))
-            log(f"step {step:6d} loss {loss_v:.4f} "
-                f"gnorm {float(metrics['grad_norm']):.3f} "
-                f"dt_p50 {p50*1e3:.1f}ms p95 {p95*1e3:.1f}ms "
-                f"skipped {counters['skipped']} "
-                f"rollbacks {counters['rollbacks']} "
-                f"resumed_from {counters['resumed_from']}")
-        if eval_every and eval_hook and step % eval_every == 0:
-            ev = eval_hook(state)
-            history.append((step, ev))
-            if eval_monitor is not None:
-                ev_scalar = _eval_scalar(ev)
-                if ev_scalar is not None and eval_monitor.observe(ev_scalar):
-                    do_rollback("eval", ev_scalar, "eval_rollbacks")
+            if skipped:
+                counters["skipped"] += 1
+                consec_skips += 1
+                if consec_skips > max_skips:
+                    diag = {"step": cur, "loss": loss_v,
+                            "grad_norm": float(metrics["grad_norm"]),
+                            **{k: v for k, v in counters.items()}}
+                    raise NonFiniteBudgetError(
+                        f"{consec_skips} consecutive non-finite steps "
+                        f"(budget {max_skips}) at step {cur}: loss={loss_v}, "
+                        f"gnorm={diag['grad_norm']} — data or optimizer state "
+                        f"is persistently poisoned", diag)
+            else:
+                consec_skips = 0
+                if monitor is not None and monitor.observe(loss_v):
+                    with spans.span(spans.TRAIN_ROLLBACK):
+                        do_rollback("loss", loss_v, "rollbacks")
                     continue
-        if ckpt_every and step % ckpt_every == 0:
-            # never checkpoint while a spike is suspected: a hot monitor
-            # means this state may be what we are about to roll away from
-            hot = ((monitor is not None and monitor.hot)
-                   or (eval_monitor is not None and eval_monitor.hot))
-            if ckpt_dir and not hot:
-                ckpt_io.save(ckpt_dir, step, state, keep=ckpt_keep,
-                             n_shards=ckpt_shards)
-            if ckpt_hook:
-                ckpt_hook(state)
+
+            if cooldown > 0:
+                cooldown -= 1
+                if cooldown == 0 and lr_scale_now != 1.0:
+                    lr_scale_now = 1.0
+                    state = dict(state)
+                    state["lr_scale"] = jnp.ones((), jnp.float32)
+                    log(f"run_loop: cooldown over at step {cur}, lr restored")
+
+            if step_hook is not None:
+                step_hook(state, metrics)
+
+            step = cur
+            if log_every and step % log_every == 0:
+                window = np.asarray(times)
+                p50, p95 = (np.percentile(window, 50),
+                            np.percentile(window, straggler_pct))
+                log(f"step {step:6d} loss {loss_v:.4f} "
+                    f"gnorm {float(metrics['grad_norm']):.3f} "
+                    f"dt_p50 {p50*1e3:.1f}ms p95 {p95*1e3:.1f}ms "
+                    f"skipped {counters['skipped']} "
+                    f"rollbacks {counters['rollbacks']} "
+                    f"resumed_from {counters['resumed_from']} "
+                    f"compiles {spans.compiles()}")
+            if eval_every and eval_hook and step % eval_every == 0:
+                with spans.span(spans.TRAIN_EVAL):
+                    ev = eval_hook(state)
+                history.append((step, ev))
+                if eval_monitor is not None:
+                    ev_scalar = _eval_scalar(ev)
+                    if (ev_scalar is not None
+                            and eval_monitor.observe(ev_scalar)):
+                        with spans.span(spans.TRAIN_ROLLBACK):
+                            do_rollback("eval", ev_scalar, "eval_rollbacks")
+                        continue
+            if ckpt_every and step % ckpt_every == 0:
+                # never checkpoint while a spike is suspected: a hot monitor
+                # means this state may be what we are about to roll away from
+                hot = ((monitor is not None and monitor.hot)
+                       or (eval_monitor is not None and eval_monitor.hot))
+                with spans.span(spans.TRAIN_CKPT):
+                    if ckpt_dir and not hot:
+                        ckpt_io.save(ckpt_dir, step, state, keep=ckpt_keep,
+                                     n_shards=ckpt_shards)
+                    if ckpt_hook:
+                        ckpt_hook(state)
+
     return {"state": state, "history": history, "step_times": list(times),
             **counters}

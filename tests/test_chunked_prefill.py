@@ -110,21 +110,29 @@ def test_decode_not_stalled_by_long_prompt():
     tick — and no tick ever interposes more than one chunk of prefill."""
     params = _params()
     sch = _sched(params, 2, n_slots=2, k=2, cache_len=64)
+    stall = []                         # prefill tokens computed per step
+
+    def step():
+        computed = sch.prefill_tokens_computed
+        sch.step()
+        stall.append(sch.prefill_tokens_computed - computed)
+
     short = sch.submit([5, 3], max_new_tokens=16)
     for _ in range(3):
-        sch.step()                     # short is prefilled + decoding
+        step()                         # short is prefilled + decoding
     assert sch.requests[short].state == ACTIVE
     long_r = sch.submit(list(range(1, 33)), max_new_tokens=4)
     grew = 0
     while not (sch.requests[long_r].state == ACTIVE
                or sch.requests[long_r].done):
         before = len(sch.requests[short].out)
-        sch.step()
+        step()
         assert sch.requests[long_r].state in (PREFILLING, ACTIVE)
         grew += len(sch.requests[short].out) > before
     assert grew >= 5                   # decode progressed during prefill
-    sch.run()
-    assert max(sch.stall_log) <= 2     # never more than one chunk per tick
+    while sch.has_work():
+        step()
+    assert max(stall) <= 2             # never more than one chunk per tick
     # and the decode dispatch bound still holds for every request
     for rid, req in sch.requests.items():
         assert req.ticks <= math.ceil(req.max_new_tokens / 2), rid
