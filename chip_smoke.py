@@ -51,8 +51,8 @@ from repro.launch.cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.launch.train import (init_sharded_state, make_batch_fn,  # noqa: E402
                                 make_pipeline, shard_setup)
-from repro.models.lm import (cache_insert_paged, init_cache,  # noqa: E402
-                             lm_decode, lm_init, lm_prefill)
+from repro.models.lm import (cache_insert_paged,  # noqa: E402
+                             init_paged_pool, lm_decode, lm_init, lm_prefill)
 from repro.optim import adamw, apply_updates, cosine_with_warmup  # noqa: E402
 from repro.serve import Scheduler, SchedulerConfig, ServeConfig  # noqa: E402
 from repro.serve.slots import COMPLETED  # noqa: E402
@@ -406,15 +406,15 @@ def logits_compare(cfg, params, check: Checks, *, batch: int,
             with qtensor_use_kernel(use_kernel), \
                     jax.default_matmul_precision(precision):
                 return lm_decode(p, cfg, pool, tok, pos,
-                                 block_tables=tables, block_size=block_size)
+                                 block_tables=tables)
         return f
 
     pre_k, pre_r = prefill(True), prefill(False)
     logits_k, cache = pre_k(params, toks)
     logits_r, _ = pre_r(params, toks)
     logits_x, _ = prefill(False, "highest")(params, toks)
-    pool = init_cache(cfg, 1 + batch * bps, block_size, dtype=cfg.dtype,
-                      kv_quant="int4")
+    pool = init_paged_pool(cfg, 1 + batch * bps, block_size,
+                           dtype=cfg.dtype, kv_quant="int4")
     write = jnp.ones((bps,), bool)
     for i in range(batch):
         row = jax.tree.map(lambda x: x[:, i:i + 1], cache)

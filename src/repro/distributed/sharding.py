@@ -250,7 +250,9 @@ def cache_spec(path, x, mesh, batch: int) -> P:
     ``batch == n_slots`` (a fixed compile-time constant — DESIGN.md §7),
     so one rule set serves static and scheduled decode; quantized caches
     ride as ``k/v -> codes|scale`` children (int8, or packed-uint8 int4
-    whose trailing head_dim/2 stays unsharded like head_dim).
+    whose trailing head_dim/2 stays unsharded like head_dim).  The paged
+    pool (``batch == n_blocks``) shards its block axis like a batch and
+    its block's tokens like a length, in codes and scales alike.
     """
     name = _leaf_name(path)
     dims = list(x.shape)
@@ -266,13 +268,18 @@ def cache_spec(path, x, mesh, batch: int) -> P:
     is_kv = (parts[-1] in ("k", "v")
              or (len(parts) >= 2 and parts[-2] in ("k", "v")
                  and parts[-1] in ("codes", "scale")))
-    if is_kv and x.ndim == 5:
-        # (r, b, len, kvh, hd-or-1)
+    if is_kv and x.ndim in (4, 5):
+        # (r, b, len, kvh, hd-or-1); a quantized paged pool (``batch`` =
+        # n_blocks) in the decode kernel's layout: codes (r, n_blocks, bs,
+        # kvh*hd_c) and scales (r, n_blocks, kvh, bs) — tokens on axis 3
+        tok = 3 if x.ndim == 4 and parts[-1] == "scale" else 2
         len_axes = tuple(rest) + ("model",)
-        L = dims[2]
+        L = dims[tok]
         if L % _axsize(mesh, list(len_axes)) != 0:
             len_axes = ("model",) if L % mesh.shape["model"] == 0 else ()
-        return P(None, bspec, len_axes if len_axes else None, None, None)
+        spec = [None, bspec] + [None] * (x.ndim - 2)
+        spec[tok] = len_axes if len_axes else None
+        return P(*spec)
     if name.endswith("ssm") and x.ndim == 5:      # (r, b, h, p, n)
         h = dims[2]
         hax = "model" if h % mesh.shape["model"] == 0 else None

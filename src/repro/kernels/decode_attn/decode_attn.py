@@ -15,27 +15,47 @@ blocks named by a block table (DESIGN.md §13).
 
 Tile layout (what the TPU compiler accepts)
 -------------------------------------------
-A cache row's trailing ``(g, hd_c)`` dims are viewed flat, so one tile is
-a lane-dense ``(tile, C)`` code block with ``C = g * hd_c`` and a
-``(tile, g)`` scale block, both with full trailing dims — Mosaic refuses
-a block of ONE kv head out of ``g``.  Every kv head is then scored by one
-MXU contraction against a block-diagonal query: row ``(h, r)`` of
-``qbd (g*rep, C)`` holds query ``q[h, r]`` in the columns of head ``h``
-and zeros elsewhere, so ``qbd @ codes^T`` is exactly each query row
-against its own head.  ``probs @ codes`` scores every head against every
-row, and the wrapper keeps the diagonal blocks.  Packed int4 is never
-interleaved in VMEM: the low and high nibble planes are the even and odd
-head dims, contracted separately against the even and odd query columns
-("parts"; 2 for int4, 1 for int8).
+A tile is a lane-dense ``(tile, C)`` code block with ``C = g * hd_c``
+(every kv head of ``tile`` tokens) plus the tile's scales, both with full
+trailing dims — Mosaic refuses a block of ONE kv head out of ``g``.  The
+ring cache stores ``(b, L, g, hd_c)`` codes and ``(b, L, g, 1)`` scales,
+viewed flat per call into ``(tile, C)`` and ``(tile, g)`` blocks.  The
+paged pool is STORED in the kernel's layout, so nothing relays it out:
+
+* codes ``(r, n_blocks, bs, C)`` — one block's tile is ``(bs, C)``;
+* scales ``(r, n_blocks, g, bs)`` fp32 — lane-dense along the block's
+  tokens.  A ``(bs, g)`` scale tile puts ``g`` (8 for granite) on the
+  128-lane axis, which TPU tiling pads 16x or relays out; ``(g, bs)``
+  fills the lanes and is exactly the ``(heads, tokens)`` orientation the
+  score tile needs.
+
+The paged grid is ``(b, bps)`` over ONE layer ``r`` of the stacked pool:
+the flattened block table, the positions and ``r`` ride scalar prefetch,
+and tile ``li`` of row ``bi`` streams block ``(r, bt[bi*bps + li])``.
+The decode layer scan hands the kernel the whole stacked pool and its
+layer index, and writes the step's new token into that pool in place
+(``models/layers.py::attn_decode_paged``): no layer is sliced out,
+copied or written back, so a decode step moves only the bytes the
+attention reads.
+
+Every kv head is scored by one MXU contraction against a block-diagonal
+query: row ``(h, r)`` of ``qbd (g*rep, C)`` holds query ``q[h, r]`` in
+the columns of head ``h`` and zeros elsewhere, so ``qbd @ codes^T`` is
+exactly each query row against its own head.  ``probs @ codes`` scores
+every head against every row, and the wrapper keeps the diagonal blocks.
+Packed int4 is never interleaved in VMEM: the low and high nibble planes
+are the even and odd head dims, contracted separately against the even
+and odd query columns ("parts"; 2 for int4, 1 for int8).
 
 Numerics follow the jnp fallback in ``attn_decode``: codes contract raw,
 the fp32 absmax scale folds into the (rows, tile) score / prob tiles
-(expanded from the (tile, g) scale block by an exact one-hot matmul),
-softcap applies before the validity bias, and a fully-masked tile's
-garbage contribution is annihilated by the next valid tile's
-``alpha = exp(-1e30 - m)`` rescale (decode always has >= 1 valid slot —
-the just-written token).  Online vs. dense softmax differ only in fp
-summation order.
+(expanded from the scale block by an exact one-hot matmul at HIGHEST
+precision: ``expand (GR, g) @ scales (g, tile)``, or its ``(tile, g)``
+transpose for the ring), softcap applies before the validity bias, and a
+fully-masked tile's garbage contribution is annihilated by the next valid
+tile's ``alpha = exp(-1e30 - m)`` rescale (decode always has >= 1 valid
+slot — the just-written token).  Online vs. dense softmax differ only in
+fp summation order.
 """
 
 from __future__ import annotations
@@ -88,26 +108,27 @@ def _ring_valid(pos, start, tile: int, cache_len: int,
     return valid
 
 
-def _attend(valid, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, s_ref,
-            acc_ref, *, rep: int, hd: int, softcap: Optional[float],
-            int4: bool):
-    """One tile of the online-softmax decode: q_ref (1, P, g*rep, C),
-    code refs (1, tile, C), scale refs (1, tile, g); state in m/s
-    (g*rep, 1) and acc (P, g*rep, C) scratch."""
-    q = q_ref[0].astype(jnp.float32)                       # (P, GR, C)
-    n_parts, gr = q.shape[0], q.shape[1]
-    g = ks_ref.shape[-1]
-    # one-hot (row -> kv head) expander: ksT[(h, r), l] = ks[l, h] exactly
+def _head_expander(gr: int, g: int):
+    """(GR, g) one-hot: row (h, r) of the block-diagonal query -> head h."""
+    rep = gr // g
     row_head = jax.lax.broadcasted_iota(jnp.int32, (gr, g), 0) // rep
     head = jax.lax.broadcasted_iota(jnp.int32, (gr, g), 1)
-    expand = (row_head == head).astype(jnp.float32)
-    hi_prec = jax.lax.Precision.HIGHEST
+    return (row_head == head).astype(jnp.float32)
 
-    k_planes = _code_planes(kc_ref[0], int4)
+
+def _attend(valid, q_ref, kc, ks_t, vc, vs_t, m_ref, s_ref, acc_ref, *,
+            hd: int, softcap: Optional[float], int4: bool):
+    """One tile of the online-softmax decode: q_ref (1, P, g*rep, C),
+    code tiles (tile, C), scales expanded to the query rows (g*rep, tile)
+    (exact: one-hot matmuls at HIGHEST); state in m/s (g*rep, 1) and acc
+    (P, g*rep, C) scratch."""
+    q = q_ref[0].astype(jnp.float32)                       # (P, GR, C)
+    n_parts = q.shape[0]
+
+    k_planes = _code_planes(kc, int4)
     s = _dot_nt(q[0], k_planes[0])
     for p in range(1, n_parts):
         s = s + _dot_nt(q[p], k_planes[p])                 # (GR, tile)
-    ks_t = _dot_nt(expand, ks_ref[0], hi_prec)             # (GR, tile)
     logits = (s * ks_t) / np.sqrt(hd)
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
@@ -119,8 +140,8 @@ def _attend(valid, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, s_ref,
     prob = jnp.exp(logits - m_new)                         # (GR, tile)
     s_ref[...] = s_ref[...] * alpha + prob.sum(axis=-1, keepdims=True)
 
-    pv = prob * _dot_nt(expand, vs_ref[0], hi_prec)
-    v_planes = _code_planes(vc_ref[0], int4)
+    pv = prob * vs_t
+    v_planes = _code_planes(vc, int4)
     for p in range(n_parts):
         acc_ref[p] = acc_ref[p] * alpha + jax.lax.dot_general(
             pv, v_planes[p], (((1,), (0,)), ((), ())),
@@ -164,16 +185,18 @@ def _diag_out(o: Array, q: Array) -> Array:
     return o.reshape(b, g, rep, hd).astype(q.dtype)
 
 
-def _geometry(q, k_codes, k_scale, bits, lead):
-    """Validate the cache leaf shapes and return (int4, parts, C)."""
+def _geometry(q, bits: int, leaves, want_codes, want_scale):
+    """Validate the cache leaf shapes against the expected
+    ``want_codes`` / ``want_scale`` (functions of ``hd_c``) and return
+    (int4, parts, C)."""
     b, g, rep, hd = q.shape
     int4 = bits == 4
     hd_c = hd // 2 if int4 else hd
-    if k_codes.shape != lead + (g, hd_c):
-        raise ValueError(f"k_codes shape {k_codes.shape} != "
-                         f"{lead + (g, hd_c)} for bits={bits}")
-    if k_scale.shape != lead + (g, 1):
-        raise ValueError(f"k_scale shape {k_scale.shape} != {lead + (g, 1)}")
+    for name, leaf, want in zip(("k_codes", "k_scale", "v_codes", "v_scale"),
+                                leaves, (want_codes, want_scale) * 2):
+        if leaf.shape != want(hd_c):
+            raise ValueError(f"{name} shape {leaf.shape} != {want(hd_c)} "
+                             f"for bits={bits}")
     return int4, (2 if int4 else 1), g * hd_c
 
 
@@ -191,7 +214,7 @@ def _decode_attn_kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                         o_ref, m_ref, s_ref, acc_ref, *,
                         n_l: int, tile_l: int, cache_len: int,
                         window: Optional[int], softcap: Optional[float],
-                        int4: bool, rep: int, hd: int):
+                        int4: bool, hd: int):
     bi = pl.program_id(0)
     li = pl.program_id(1)
 
@@ -200,8 +223,11 @@ def _decode_attn_kernel(pos_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
         _init_state(m_ref, s_ref, acc_ref)
 
     valid = _ring_valid(pos_ref[bi], li * tile_l, tile_l, cache_len, window)
-    _attend(valid, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, s_ref,
-            acc_ref, rep=rep, hd=hd, softcap=softcap, int4=int4)
+    expand = _head_expander(q_ref.shape[2], ks_ref.shape[-1])
+    hi_prec = jax.lax.Precision.HIGHEST
+    _attend(valid, q_ref, kc_ref[0], _dot_nt(expand, ks_ref[0], hi_prec),
+            vc_ref[0], _dot_nt(expand, vs_ref[0], hi_prec), m_ref, s_ref,
+            acc_ref, hd=hd, softcap=softcap, int4=int4)
 
     @pl.when(li == n_l - 1)
     def _():
@@ -234,7 +260,9 @@ def decode_attn_pallas(q: Array, k_codes: Array, k_scale: Array,
     """
     b, g, rep, hd = q.shape
     L = k_codes.shape[1]
-    int4, parts, c = _geometry(q, k_codes, k_scale, bits, (b, L))
+    int4, parts, c = _geometry(
+        q, bits, (k_codes, k_scale, v_codes, v_scale),
+        lambda hd_c: (b, L, g, hd_c), lambda hd_c: (b, L, g, 1))
     tile_l = _pick_tile_l(L, block_l)
     n_l = L // tile_l
     gr = g * rep
@@ -243,7 +271,7 @@ def decode_attn_pallas(q: Array, k_codes: Array, k_scale: Array,
 
     kernel = functools.partial(
         _decode_attn_kernel, n_l=n_l, tile_l=tile_l, cache_len=L,
-        window=window, softcap=softcap, int4=int4, rep=rep, hd=hd)
+        window=window, softcap=softcap, int4=int4, hd=hd)
     # index maps see the scalar-prefetch ref as a trailing arg
     q_spec = pl.BlockSpec((1, parts, gr, c), lambda bi, li, ps: (bi, 0, 0, 0))
     code_spec = pl.BlockSpec((1, tile_l, c), lambda bi, li, ps: (bi, li, 0))
@@ -262,23 +290,25 @@ def decode_attn_pallas(q: Array, k_codes: Array, k_scale: Array,
 
 
 # --------------------------------------------------------------------------
-# Paged variant (DESIGN.md §13): the KV lives in a SHARED block pool
-# ((n_blocks, bs, g, hd[/2]) codes + (n_blocks, bs, g, 1) scales) and each
-# batch row's cache is named by an int32 block table (b, bps).  The grid is
-# (batch, bps) with the BLOCK axis innermost/"arbitrary": the flattened
-# table and the positions ride scalar prefetch, so tile li of row bi
-# streams pool block ``bt[bi, li]`` from HBM — one tile per logical block,
-# same online-softmax dataflow and validity math as the ring kernel with
-# tile_l = block_size and slots j = li*bs + iota.
+# Paged variant (DESIGN.md §13): the KV lives in a SHARED block pool,
+# stacked over the layer scan's repeats and stored in the kernel's layout
+# ((r, n_blocks, bs, g*hd_c) codes + (r, n_blocks, g, bs) scales), and
+# each batch row's cache is named by an int32 block table (b, bps).  The
+# grid is (batch, bps) with the BLOCK axis innermost/"arbitrary": the
+# flattened table, the positions and the layer index ride scalar
+# prefetch, so tile li of row bi streams block ``(r, bt[bi, li])`` from
+# HBM — one tile per logical block, same online-softmax dataflow and
+# validity math as the ring kernel with tile_l = block_size and slots
+# j = li*bs + iota.
 # --------------------------------------------------------------------------
 
-def _decode_attn_paged_kernel(bt_ref, pos_ref, q_ref, kc_ref, ks_ref,
-                              vc_ref, vs_ref, o_ref, m_ref, s_ref, acc_ref,
-                              *, bps: int, block_size: int,
+def _decode_attn_paged_kernel(bt_ref, pos_ref, layer_ref, q_ref, kc_ref,
+                              ks_ref, vc_ref, vs_ref, o_ref, m_ref, s_ref,
+                              acc_ref, *, bps: int, block_size: int,
                               window: Optional[int],
                               softcap: Optional[float], int4: bool,
-                              rep: int, hd: int):
-    del bt_ref                       # consumed by the index maps
+                              hd: int):
+    del bt_ref, layer_ref            # consumed by the index maps
     bi = pl.program_id(0)
     li = pl.program_id(1)
 
@@ -291,8 +321,16 @@ def _decode_attn_paged_kernel(bt_ref, pos_ref, q_ref, kc_ref, ks_ref,
     # formula carries over unchanged
     valid = _ring_valid(pos_ref[bi], li * block_size, block_size,
                         bps * block_size, window)
-    _attend(valid, q_ref, kc_ref, ks_ref, vc_ref, vs_ref, m_ref, s_ref,
-            acc_ref, rep=rep, hd=hd, softcap=softcap, int4=int4)
+    expand = _head_expander(q_ref.shape[2], ks_ref.shape[0])
+    hi_prec = jax.lax.Precision.HIGHEST
+
+    def rows(scales):                # (g, bs) -> (GR, bs), exact
+        return jnp.dot(expand, scales, precision=hi_prec,
+                       preferred_element_type=jnp.float32)
+
+    _attend(valid, q_ref, kc_ref[...], rows(ks_ref[...]), vc_ref[...],
+            rows(vs_ref[...]), m_ref, s_ref, acc_ref, hd=hd,
+            softcap=softcap, int4=int4)
 
     @pl.when(li == bps - 1)
     def _():
@@ -301,37 +339,42 @@ def _decode_attn_paged_kernel(bt_ref, pos_ref, q_ref, kc_ref, ks_ref,
 
 def decode_attn_paged_pallas(q: Array, k_codes: Array, k_scale: Array,
                              v_codes: Array, v_scale: Array,
-                             block_tables: Array, pos: Array, *,
+                             block_tables: Array, pos: Array, layer, *,
                              bits: int = 8, window: Optional[int] = None,
                              softcap: Optional[float] = None,
                              interpret: Optional[bool] = None) -> Array:
-    """q (b, g, rep, hd) x paged quantized pool -> (b, g, rep, hd).
+    """q (b, g, rep, hd) x layer ``layer`` of the stacked paged pool ->
+    (b, g, rep, hd).
 
-    ``k_codes``/``v_codes``: int8 (n_blocks, bs, g, hd) or packed-int4
-    uint8 (n_blocks, bs, g, hd/2); scales (n_blocks, bs, g, 1) fp32;
-    ``block_tables`` (b, bps) int32 pool block ids; ``pos`` (b,) int32.
+    ``k_codes``/``v_codes``: int8 (r, n_blocks, bs, g*hd) or packed-int4
+    uint8 (r, n_blocks, bs, g*hd/2); scales (r, n_blocks, g, bs) fp32;
+    ``block_tables`` (b, bps) int32 pool block ids; ``pos`` (b,) int32;
+    ``layer`` int32 scalar in [0, r).
     """
     b, g, rep, hd = q.shape
-    n_blocks, bs = k_codes.shape[0], k_codes.shape[1]
-    int4, parts, c = _geometry(q, k_codes, k_scale, bits, (n_blocks, bs))
+    n_rep, n_blocks, bs = k_codes.shape[:3]
+    int4, parts, c = _geometry(
+        q, bits, (k_codes, k_scale, v_codes, v_scale),
+        lambda hd_c: (n_rep, n_blocks, bs, g * hd_c),
+        lambda hd_c: (n_rep, n_blocks, g, bs))
     bps = block_tables.shape[1]
     gr = g * rep
-    kc, ks = _flat(k_codes, k_scale)
-    vc, vs = _flat(v_codes, v_scale)
 
     kernel = functools.partial(
         _decode_attn_paged_kernel, bps=bps, block_size=bs,
-        window=window, softcap=softcap, int4=int4, rep=rep, hd=hd)
+        window=window, softcap=softcap, int4=int4, hd=hd)
     # index maps see the scalar-prefetch refs as trailing args: tile li of
-    # row bi reads pool block bt[bi * bps + li]
+    # row bi reads block bt[bi * bps + li] of layer ly[0]
     q_spec = pl.BlockSpec((1, parts, gr, c),
-                          lambda bi, li, bt, ps: (bi, 0, 0, 0))
-    code_spec = pl.BlockSpec((1, bs, c),
-                             lambda bi, li, bt, ps: (bt[bi * bps + li], 0, 0))
-    scale_spec = pl.BlockSpec((1, bs, g),
-                              lambda bi, li, bt, ps: (bt[bi * bps + li], 0, 0))
+                          lambda bi, li, bt, ps, ly: (bi, 0, 0, 0))
+    code_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, bs, c),
+        lambda bi, li, bt, ps, ly: (ly[0], bt[bi * bps + li], 0, 0))
+    scale_spec = pl.BlockSpec(
+        (pl.squeezed, pl.squeezed, g, bs),
+        lambda bi, li, bt, ps, ly: (ly[0], bt[bi * bps + li], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(b, bps),
+        num_scalar_prefetch=3, grid=(b, bps),
         in_specs=[q_spec, code_spec, scale_spec, code_spec, scale_spec],
         out_specs=q_spec, scratch_shapes=_scratch(parts, gr, c))
     out = pl.pallas_call(
@@ -340,5 +383,6 @@ def decode_attn_paged_pallas(q: Array, k_codes: Array, k_scale: Array,
         compiler_params=_PARAMS,
         interpret=resolve_interpret(interpret), name="decode_attn_paged",
     )(block_tables.astype(jnp.int32).reshape(-1), pos.astype(jnp.int32),
-      _block_diag_query(q, parts), kc, ks, vc, vs)
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      _block_diag_query(q, parts), k_codes, k_scale, v_codes, v_scale)
     return _diag_out(out, q)

@@ -33,12 +33,13 @@ def decode_attn(q, k_codes, k_scale, v_codes, v_scale, pos, *,
 @functools.partial(jax.jit,
                    static_argnames=("bits", "window", "softcap"))
 def decode_attn_paged(q, k_codes, k_scale, v_codes, v_scale,
-                      block_tables, pos, *,
+                      block_tables, pos, layer, *,
                       bits: int = 8, window=None, softcap=None):
-    """One fused decode step against the PAGED pool: q (b, g, rep, hd),
-    pool codes (n_blocks, bs, g, hd[/2]) + scales (n_blocks, bs, g, 1)
-    shared by all rows, int32 ``block_tables`` (b, bps), per-row
-    positions (b,) -> (b, g, rep, hd)."""
+    """One fused decode step against layer ``layer`` of the stacked PAGED
+    pool: q (b, g, rep, hd), pool codes (r, n_blocks, bs, g*hd[/2]) +
+    scales (r, n_blocks, g, bs) shared by all rows, int32
+    ``block_tables`` (b, bps), per-row positions (b,), int32 scalar
+    ``layer`` -> (b, g, rep, hd)."""
     return decode_attn_paged_pallas(
-        q, k_codes, k_scale, v_codes, v_scale, block_tables, pos,
+        q, k_codes, k_scale, v_codes, v_scale, block_tables, pos, layer,
         bits=bits, window=window, softcap=softcap)

@@ -86,29 +86,33 @@ def decode_attn_ref(q: Array, k_codes: Array, k_scale: Array,
                       v.astype(q.dtype))
 
 
-def gather_pool_ref(pool: Array, block_tables: Array) -> Array:
-    """Pool leaf (n_blocks, bs, g, x) + tables (b, bps) -> the dense
-    per-row ring view (b, bps*bs, g, x) the paged kernel must reproduce
-    reads over."""
-    out = pool[block_tables]                     # (b, bps, bs, g, x)
-    return out.reshape((out.shape[0], out.shape[1] * out.shape[2])
-                       + out.shape[3:])
+def gather_pool_ref(codes: Array, scale: Array, block_tables: Array,
+                    layer, g: int):
+    """Layer ``layer`` of a stacked pool stored in the kernel's layout
+    (codes (r, n_blocks, bs, g*x), scales (r, n_blocks, g, bs)) + tables
+    (b, bps) -> the dense per-row ring view (codes (b, bps*bs, g, x),
+    scales (b, bps*bs, g, 1)) the paged kernel must reproduce reads over."""
+    c = codes[layer][block_tables]               # (b, bps, bs, g*x)
+    s = scale[layer][block_tables]               # (b, bps, g, bs)
+    b, bps, bs = c.shape[:3]
+    return (c.reshape(b, bps * bs, g, -1),
+            jnp.swapaxes(s, 2, 3).reshape(b, bps * bs, g, 1))
 
 
 def decode_attn_paged_ref(q: Array, k_codes: Array, k_scale: Array,
                           v_codes: Array, v_scale: Array,
-                          block_tables: Array, pos: Array, *,
+                          block_tables: Array, pos: Array, layer, *,
                           bits: int = 8, window: Optional[int] = None,
                           softcap: Optional[float] = None) -> Array:
-    """Oracle for the paged kernel: gather each row's blocks into the
-    dense ring layout, then run the EXACT dense-ring oracle on the view.
-    Codes/scales live in a shared (n_blocks, bs, g, hd[/2]) pool indexed
-    by int32 ``block_tables`` (b, bps); everything else is unchanged —
-    paged attention IS ring attention over a scattered address space.
+    """Oracle for the paged kernel: gather each row's blocks of layer
+    ``layer`` into the dense ring layout, then run the EXACT dense-ring
+    oracle on the view.  Codes/scales live in a shared pool stacked over
+    layers (see :func:`gather_pool_ref`) indexed by int32 ``block_tables``
+    (b, bps); everything else is unchanged — paged attention IS ring
+    attention over a scattered address space.
     """
-    return decode_attn_ref(
-        q, gather_pool_ref(k_codes, block_tables),
-        gather_pool_ref(k_scale, block_tables),
-        gather_pool_ref(v_codes, block_tables),
-        gather_pool_ref(v_scale, block_tables), pos,
-        bits=bits, window=window, softcap=softcap)
+    g = q.shape[1]
+    kc, ks = gather_pool_ref(k_codes, k_scale, block_tables, layer, g)
+    vc, vs = gather_pool_ref(v_codes, v_scale, block_tables, layer, g)
+    return decode_attn_ref(q, kc, ks, vc, vs, pos, bits=bits,
+                           window=window, softcap=softcap)

@@ -460,42 +460,52 @@ def attn_decode(
 # slot.  The logical ring layout is unchanged — table entry i of a slot
 # holds ring slots [i*bs, (i+1)*bs) — so ring validity, RoPE positions and
 # the quantizer are byte-compatible with the dense path; only the physical
-# address of a slot's KV moves (and can be shared across tables).
+# address of a slot's KV moves (and can be shared across tables).  Pool
+# leaves stay stacked over the layer scan's repeats and every access names
+# its layer: a quantized pool is stored in the decode kernel's layout
+# (``lm.init_paged_pool``: codes (r, n_blocks, bs, g*hd_c), scales
+# (r, n_blocks, g, bs)), a dense one as (r, n_blocks, bs, g, hd).
 
-def paged_gather(cache, bt):
-    """Gather pool blocks into a dense per-row view.
+def paged_gather(pool, bt, layer):
+    """Gather layer ``layer``'s pool blocks into a dense per-row view.
 
-    ``cache``: pool leaf ``(n_blocks, bs, kvh, ...)`` (dense or
-    codes/scale dict); ``bt``: int32 block table ``(b, bps)``.  Returns
-    the ``(b, bps*bs, kvh, ...)`` view whose entries are byte-identical
-    to what the dense-ring cache of the same requests would hold.
+    ``pool``: stacked pool leaf (dense or codes/scale dict); ``bt``: int32
+    block table ``(b, bps)``.  Returns the ``(b, bps*bs, kvh, ...)`` view
+    (quantized: codes ``(b, L, kvh, hd_c)``, scales ``(b, L, kvh, 1)``)
+    whose entries are byte-identical to what the dense-ring cache of the
+    same requests would hold.  Only the gathered rows are re-laid out.
     """
-    def g(a):
-        with jax.named_scope(spans.KV_CACHE):
-            out = a[bt]                          # (b, bps, bs, ...)
-            return out.reshape((out.shape[0], out.shape[1] * out.shape[2])
-                               + out.shape[3:])
-    if _is_quantized_cache(cache):
-        return {"codes": g(cache["codes"]), "scale": g(cache["scale"])}
-    return g(cache)
-
-
-def _cache_write_paged(cache, new, bt, slot, block_size):
-    """Write the (b, kvh, hd) vector ``new`` at ring slot ``slot`` of each
-    row, routed through the block table into the pool.  Rows whose table
-    entry is 0 (cleared/idle) land in the reserved dump block."""
     with jax.named_scope(spans.KV_CACHE):
-        bi = slot // block_size
-        off = slot % block_size
-        bid = jnp.take_along_axis(bt, bi[:, None], axis=1)[:, 0]
-        if _is_quantized_cache(cache):
-            bits = 4 if cache["codes"].dtype == jnp.uint8 else 8
-            q = kv_quantize(new[:, None], bits)  # (b,1,kvh,*)
+        if _is_quantized_cache(pool):
+            codes = pool["codes"][layer, bt]         # (b, bps, bs, C)
+            scale = pool["scale"][layer, bt]         # (b, bps, g, bs)
+            b, bps, g, bs = scale.shape
+            return {"codes": codes.reshape(b, bps * bs, g, -1),
+                    "scale": jnp.swapaxes(scale, 2, 3).reshape(
+                        b, bps * bs, g, 1)}
+        out = pool[layer, bt]                        # (b, bps, bs, g, hd)
+        return out.reshape((out.shape[0], out.shape[1] * out.shape[2])
+                           + out.shape[3:])
+
+
+def _pool_write(pool, new, layer, bid, off):
+    """Write each row's (kvh, hd) vector ``new[i]`` into layer ``layer``
+    of the stacked pool at block ``bid[i]``, offset ``off[i]``, where it
+    lies: one token per row, nothing else of the pool is touched.  Rows
+    whose table entry is 0 (cleared/idle) land in the reserved dump
+    block."""
+    with jax.named_scope(spans.KV_CACHE):
+        if _is_quantized_cache(pool):
+            bits = 4 if pool["codes"].dtype == jnp.uint8 else 8
+            q = kv_quantize(new[:, None], bits)      # (b, 1, kvh, *)
+            b = new.shape[0]
             return {
-                "codes": cache["codes"].at[bid, off].set(q["codes"][:, 0]),
-                "scale": cache["scale"].at[bid, off].set(q["scale"][:, 0]),
+                "codes": pool["codes"].at[layer, bid, off].set(
+                    q["codes"].reshape(b, -1)),
+                "scale": pool["scale"].at[layer, bid, :, off].set(
+                    q["scale"].reshape(b, -1)),
             }
-        return cache.at[bid, off].set(new.astype(cache.dtype))
+        return pool.at[layer, bid, off].set(new.astype(pool.dtype))
 
 
 def attn_decode_paged(
@@ -503,25 +513,29 @@ def attn_decode_paged(
     spec: AttnSpec,
     x: Array,                      # (b, 1, d_model) — one new token
     pos: Array,                    # (b,) int32 current position
-    cache_k,                       # pool leaf (n_blocks, bs, kvh, hd) / dict
-    cache_v,
+    pool_k,                        # stacked pool leaf (dense or dict)
+    pool_v,
     block_tables: Array,           # (b, bps) int32 pool block ids
-    block_size: int,
+    layer: Array,                  # int32 scalar: the layer scan's repeat
 ):
-    """Single-token decode against the PAGED KV pool.
+    """Single-token decode of layer ``layer`` against the stacked PAGED KV
+    pool.
 
-    The new K/V is quantized and written through the block table first
-    (same per-vector quantizer, same ring slot -> same bytes as the dense
-    path), then scored either by the block-table-indexed Pallas kernel or
-    by gathering the row's blocks into a dense view and running the exact
-    dense-ring fallback math on it — op-for-op identical to
-    :func:`attn_decode`'s fallback, so greedy outputs cannot drift.
-    Returns (out, new_cache_k, new_cache_v) with POOL-shaped caches.
+    The new K/V is quantized and written through the block table into
+    the pool where it lies (same per-vector quantizer, same ring slot ->
+    same bytes as the dense path), then scored either by the
+    layer-indexed, block-table-indexed Pallas kernel, which streams the
+    blocks from the stacked pool, or by gathering the row's blocks into a
+    dense view and running the exact dense-ring fallback math on it —
+    op-for-op identical to :func:`attn_decode`'s fallback, so greedy
+    outputs cannot drift.  Returns (out, new_pool_k, new_pool_v).
     """
     b = x.shape[0]
     g = spec.n_kv_heads
     rep = spec.n_heads // g
     hd = spec.head_dim
+    quantized = _is_quantized_cache(pool_k)
+    block_size = (pool_k["codes"] if quantized else pool_k).shape[2]
     bps = block_tables.shape[1]
     cache_len = bps * block_size
 
@@ -529,30 +543,31 @@ def attn_decode_paged(
     q = apply_rope(q, pos[:, None], spec.rope_theta)
     k = apply_rope(k, pos[:, None], spec.rope_theta)
     slot = (pos % cache_len).astype(jnp.int32)
-    cache_k = _cache_write_paged(cache_k, k[:, 0], block_tables, slot,
-                                 block_size)
-    cache_v = _cache_write_paged(cache_v, v[:, 0], block_tables, slot,
-                                 block_size)
+    bid = jnp.take_along_axis(block_tables, (slot // block_size)[:, None],
+                              axis=1)[:, 0]
+    off = slot % block_size
+    pool_k = _pool_write(pool_k, k[:, 0], layer, bid, off)
+    pool_v = _pool_write(pool_v, v[:, 0], layer, bid, off)
 
     q4 = q.reshape(b, g, rep, hd)
-    if _is_quantized_cache(cache_k) and kernel_enabled():
+    if quantized and kernel_enabled():
         # fused path: the kernel's grid walks the block table and streams
         # each block's packed codes from HBM exactly once
         from repro.kernels.decode_attn import decode_attn_paged
-        bits = 4 if cache_k["codes"].dtype == jnp.uint8 else 8
-        o = decode_attn_paged(q4, cache_k["codes"], cache_k["scale"],
-                              cache_v["codes"], cache_v["scale"],
-                              block_tables, pos, bits=bits,
+        bits = 4 if pool_k["codes"].dtype == jnp.uint8 else 8
+        o = decode_attn_paged(q4, pool_k["codes"], pool_k["scale"],
+                              pool_v["codes"], pool_v["scale"],
+                              block_tables, pos, layer, bits=bits,
                               window=spec.window, softcap=spec.softcap)
         o = o.reshape(b, 1, spec.q_dim)
-        return matmul(o, params["wo"]), cache_k, cache_v
+        return matmul(o, params["wo"]), pool_k, pool_v
 
     # jnp fallback: gather the row's blocks into the dense-ring view and
     # run the EXACT attn_decode fallback ops on it (same hoisted
     # unpack-once discipline, same validity formula) — bit-identical to
     # the dense scheduler by construction
-    ck = paged_gather(cache_k, block_tables)
-    cv = paged_gather(cache_v, block_tables)
+    ck = paged_gather(pool_k, block_tables, layer)
+    cv = paged_gather(pool_v, block_tables, layer)
     k_dense = _cache_codes(ck) if _is_quantized_cache(ck) else ck
     v_dense = _cache_codes(cv) if _is_quantized_cache(cv) else cv
     if _is_quantized_cache(ck):
@@ -580,7 +595,7 @@ def attn_decode_paged(
         o = jnp.einsum("bgrl,blgd->bgrd", probs.astype(x.dtype),
                        v_dense.astype(x.dtype))
     o = o.reshape(b, 1, spec.q_dim)
-    return matmul(o, params["wo"]), cache_k, cache_v
+    return matmul(o, params["wo"]), pool_k, pool_v
 
 
 def attn_chunk_apply(

@@ -483,6 +483,42 @@ def init_cache(cfg: LMConfig, batch: int, cache_len: int,
     return cache
 
 
+def init_paged_pool(cfg: LMConfig, n_blocks: int, block_size: int,
+                    dtype=jnp.bfloat16, kv_quant=False) -> Dict[str, Any]:
+    """Paged KV pool (DESIGN.md §13): ``n_blocks`` blocks of
+    ``block_size`` tokens shared by every slot's block table, stacked over
+    repeats like :func:`init_cache`.  Attention-family patterns only (the
+    paged scheduler needs every ring to cover ``cache_len``).
+
+    A quantized pool is stored in the decode kernel's layout, so the tick
+    reads and writes it where it lies: codes ``(r, n_blocks, block_size,
+    kvh*hd_c)`` (lane-dense rows of every kv head) and fp32 scales
+    ``(r, n_blocks, kvh, block_size)`` (lane-dense along the block's
+    tokens).  A dense pool is ``(r, n_blocks, block_size, kvh, hd)``.
+    """
+    bad = [k for k in cfg.pattern if k not in ("attn", "local")]
+    if bad:
+        raise ValueError(f"a paged pool needs attn/local-only patterns, "
+                         f"got {bad}")
+    r, g, hd = cfg.n_repeats, cfg.n_kv_heads, cfg.hd
+    bits = layers.kv_bits(kv_quant)
+
+    def kv():
+        if not bits:
+            return jnp.zeros((r, n_blocks, block_size, g, hd), dtype)
+        hd_c = hd // 2 if bits == 4 else hd
+        return {"codes": jnp.zeros((r, n_blocks, block_size, g * hd_c),
+                                   jnp.uint8 if bits == 4 else jnp.int8),
+                "scale": jnp.ones((r, n_blocks, g, block_size),
+                                  jnp.float32)}
+
+    pool: Dict[str, Any] = {"unit": {f"b{i}_{kind}": {"k": kv(), "v": kv()}
+                                     for i, kind in enumerate(cfg.pattern)}}
+    if cfg.shared_attn_every:
+        pool["shared"] = {"k": kv(), "v": kv()}
+    return pool
+
+
 def cache_insert(pool_cache, row_cache, slot):
     """Insert a single-request cache (batch=1, same ``cache_len``) into a
     slot-pool cache at batch index ``slot``.
@@ -506,27 +542,38 @@ def cache_insert(pool_cache, row_cache, slot):
 def cache_insert_paged(pool_cache, row_cache, table, write_mask):
     """Scatter a single-request cache row into PAGED pool blocks.
 
-    ``pool_cache`` leaves are ``(r, n_blocks, bs, ...)``; ``row_cache`` is
-    the batch=1 full-``cache_len`` row (``(r, 1, bps*bs, ...)``, same
-    kv_quant layout).  ``table`` (bps,) int32 gives the destination block
-    per chunk; chunks with ``write_mask`` False (prefix blocks SHARED from
-    the trie, whose bytes are already in the pool) are redirected into the
-    reserved dump block 0 so a consumer never rewrites a shared block.
-    Written chunks land byte-identical to what :func:`cache_insert` puts
-    in a dense ring row, because chunk i of the row IS ring slots
-    ``[i*bs, (i+1)*bs)``.
+    ``pool_cache`` is an :func:`init_paged_pool` pool; ``row_cache`` the
+    batch=1 full-``cache_len`` row (``(r, 1, bps*bs, ...)``, same
+    kv_quant format), re-laid out here into the pool's block layout (a
+    one-request-sized op).  ``table`` (bps,) int32 gives the destination
+    block per chunk; chunks with ``write_mask`` False (prefix blocks
+    SHARED from the trie, whose bytes are already in the pool) are
+    redirected into the reserved dump block 0 so a consumer never
+    rewrites a shared block.  Written chunks land byte-identical to what
+    :func:`cache_insert` puts in a dense ring row, because chunk i of the
+    row IS ring slots ``[i*bs, (i+1)*bs)``.
     """
     bps = table.shape[0]
 
+    def blocks(row):                                 # (r, bps, bs, ...)
+        row = jax.lax.squeeze(row, (1,))
+        return row.reshape((row.shape[0], bps, -1) + row.shape[2:])
+
     def one(pool, row):
-        bs = pool.shape[2]
-        row = jax.lax.squeeze(row, (1,))             # (r, bps*bs, ...)
-        row = row.reshape((row.shape[0], bps, bs) + row.shape[2:])
-        return pool.at[:, bids].set(row.astype(pool.dtype))
+        if layers._is_quantized_cache(pool):
+            codes = blocks(row["codes"])             # (r, bps, bs, g, hd_c)
+            scale = blocks(row["scale"])[..., 0]     # (r, bps, bs, g)
+            row = {"codes": codes.reshape(codes.shape[:3] + (-1,)),
+                   "scale": jnp.swapaxes(scale, 2, 3)}
+        else:
+            row = blocks(row)
+        return jax.tree.map(
+            lambda p, u: p.at[:, bids].set(u.astype(p.dtype)), pool, row)
 
     with jax.named_scope(spans.KV_CACHE):
         bids = jnp.where(write_mask, table, 0).astype(jnp.int32)
-        return jax.tree.map(one, pool_cache, row_cache)
+        return jax.tree.map(one, pool_cache, row_cache,
+                            is_leaf=layers._is_quantized_cache)
 
 
 # ==========================================================================
@@ -864,16 +911,16 @@ def lm_prefill_chunk(params, cfg: LMConfig, cache, tokens: Array,
 
 def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
               token_mask: Optional[Array] = None,
-              block_tables: Optional[Array] = None, block_size: int = 0):
+              block_tables: Optional[Array] = None):
     """One-token decode.  tokens: (b, 1[, codebooks]); pos: (b,) int32.
 
     ``token_mask`` (b,) bool — live rows under continuous batching (free /
     retired slots decode along but must not consume MoE expert capacity).
     With ``block_tables`` (b, bps) int32, ``cache`` is the PAGED pool
-    (attn leaves shaped ``(r, n_blocks, block_size, kvh, ...)``, shared by
-    every row) and attention reads/writes route through the tables
-    (DESIGN.md §13); paged mode requires an attention-only full-ring
-    pattern.  Returns (logits (b, 1, ...), new_cache).
+    (:func:`init_paged_pool`, shared by every row) and attention
+    reads/writes route through the tables into each layer's blocks where
+    they lie (DESIGN.md §13); paged mode requires an attention-only
+    full-ring pattern.  Returns (logits (b, 1, ...), new_cache).
     """
     if block_tables is not None:
         bad = [k for k in cfg.pattern if k not in ("attn", "local")]
@@ -882,7 +929,9 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
                              f"patterns, got {bad}")
     x = _embed(params, cfg, tokens)
 
-    def unit_body(x, scanned):
+    def unit_body(x, scanned, layer=None):
+        # paged: ``unit_c`` is the whole stacked pool and ``layer`` the
+        # repeat whose blocks attention reads and writes
         unit_p, unit_c = scanned
         new_c = {}
         for i, kind in enumerate(cfg.pattern):
@@ -900,7 +949,7 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
                         o, ck, cv = attn_decode_paged(
                             p["attn"], spec, h, pos,
                             unit_c[name]["k"], unit_c[name]["v"],
-                            block_tables, block_size)
+                            block_tables, layer)
                     else:
                         o, ck, cv = attn_decode(p["attn"], spec, h, pos,
                                                 unit_c[name]["k"],
@@ -952,7 +1001,7 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
                 o, ck, cv = attn_decode_paged(
                     params["shared"]["attn"], cfg.attn_spec("attn"), hs,
                     pos, unit_c["__shared__"]["k"],
-                    unit_c["__shared__"]["v"], block_tables, block_size)
+                    unit_c["__shared__"]["v"], block_tables, layer)
             else:
                 o, ck, cv = attn_decode(params["shared"]["attn"],
                                         cfg.attn_spec("attn"), hs, pos,
@@ -968,12 +1017,18 @@ def lm_decode(params, cfg: LMConfig, cache, tokens: Array, pos: Array,
     if cfg.shared_attn_every:
         scanned_cache["__shared__"] = cache["shared"]
 
-    # Carry the FULL stacked cache and dynamic-update-slice the repeat `r`
-    # in place: a scan emitting the new cache as stacked ys double-buffers
-    # the whole multi-GB KV cache (xs + ys live simultaneously); DUS on the
-    # carry aliases (§Perf log: 30.9 -> ~10 GB/dev on 32k x 128 decode).
+    # Carry the FULL stacked cache.  A scan emitting the new cache as
+    # stacked ys would double-buffer the whole KV cache (xs + ys live
+    # simultaneously).  The paged pool is read and written where it lies:
+    # each layer writes its new token into the stacked leaves at
+    # (r, block, offset) and attention reads layer r's blocks from them.
+    # The dense ring (and lm_prefill_chunk's partial cache) still slice
+    # repeat `r` out of the carry and dynamic-update-slice it back.
     def carry_body(carry, unit_p):
         x, full_cache, r = carry
+        if block_tables is not None:
+            x, full_cache = unit_body(x, (unit_p, full_cache), r)
+            return (x, full_cache, r + 1), None
         with jax.named_scope(spans.KV_CACHE):
             unit_c = jax.tree.map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, keepdims=False),

@@ -93,6 +93,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Set, Union
 
 import jax
@@ -102,8 +103,8 @@ import numpy as np
 from repro import spans
 from repro.core.qtensor import qtensor_act_fmt, qtensor_use_kernel
 from repro.models.lm import (LMConfig, cache_insert, cache_insert_paged,
-                             init_cache, lm_decode, lm_prefill,
-                             lm_prefill_chunk, quantize_cache)
+                             init_cache, init_paged_pool, lm_decode,
+                             lm_prefill, lm_prefill_chunk, quantize_cache)
 
 from .block_pool import BlockPool
 from .engine import (Engine, ServeConfig, attn_only, bucket_cache_len,
@@ -116,6 +117,50 @@ from .slots import (COMPLETED, DECODING, FAILED, PREEMPTED, PREFILLING,
 COUNTER_KEYS = ("submitted", "admitted", "completed", "timed_out",
                 "rejected", "shed", "preempted", "resumed", "failed",
                 "nan_events", "nan_retries")
+
+
+def decode_tick(cfg: LMConfig, scfg: ServeConfig, k: int, p, cache,
+                state, key, tables=None):
+    """One scheduler tick: ``k`` decode steps of every slot in one
+    program.  ``cache`` is the slot-pool cache, or with ``tables`` (the
+    slots' block tables) the paged pool.  Returns (cache, state, emitted,
+    bad), the last two ``(k, n_slots)``: each step's sampled token (-1
+    where the slot emitted none) and its non-finite mask."""
+    mnt, eos = state["mnt"], state["eos"]
+
+    def body(carry, kk):
+        cache, tok, pos, steps, active = carry
+        pos2 = jnp.where(active, pos + 1, pos)
+        with qtensor_use_kernel(scfg.use_kernel), \
+                qtensor_act_fmt(scfg.act_fmt):
+            logits, cache = lm_decode(p, cfg, cache, tok[:, None], pos2,
+                                      token_mask=active,
+                                      block_tables=tables)
+        # non-finite guard (DESIGN.md §10): a poisoned slot is
+        # done-masked INSIDE the scan — it stops sampling, stops
+        # writing KV, and emits nothing from the bad step on; the
+        # (k, n_slots) poison mask rides the existing per-tick
+        # transfer so the guard costs one reduction, not a sync
+        ok = jnp.isfinite(logits[:, 0]).all(axis=-1)
+        bad = active & ~ok
+        live = active & ok
+        with jax.named_scope(spans.SAMPLE):
+            sampled = sample_token(logits[:, 0], kk, scfg.temperature)
+        new_tok = jnp.where(live, sampled, tok).astype(jnp.int32)
+        steps2 = jnp.where(live, steps + 1, steps)
+        emitted = jnp.where(live, new_tok, -1)
+        done = (steps2 >= mnt) | (new_tok == eos) | bad
+        return ((cache, new_tok, pos2, steps2, active & ~done),
+                (emitted, bad))
+
+    keys = jax.random.split(key, k)
+    carry = (cache, state["tok"], state["pos"], state["steps"],
+             state["active"])
+    (cache, tok, pos, steps, active), (em, bad) = jax.lax.scan(
+        body, carry, keys)
+    new_state = {"tok": tok, "pos": pos, "steps": steps,
+                 "mnt": mnt, "eos": eos, "active": active}
+    return cache, new_state, em, bad
 
 
 @dataclasses.dataclass
@@ -290,8 +335,8 @@ class Scheduler:
                     f"pool_blocks={nb} cannot hold one context "
                     f"({self._bps} blocks + the null block)")
             self.block_pool = BlockPool(nb)
-            self._pool_cache = init_cache(cfg, nb, bs, dtype=dt,
-                                          kv_quant=scfg.kv_quant)
+            self._pool_cache = init_paged_pool(cfg, nb, bs, dtype=dt,
+                                               kv_quant=scfg.kv_quant)
             # host mirror is authoritative; the device copy refreshes
             # lazily before a tick when any row changed
             self._tables_host = np.zeros((n, self._bps), np.int32)
@@ -335,6 +380,8 @@ class Scheduler:
             with jax.named_scope(spans.SAMPLE):
                 return sample_token(logits, key, scfg.temperature)
 
+        tick = functools.partial(decode_tick, cfg, scfg, k)
+
         def _prefill_fn(p, toks, lens, key):
             with qtensor_use_kernel(scfg.use_kernel), \
                     qtensor_act_fmt(scfg.act_fmt):
@@ -358,41 +405,6 @@ class Scheduler:
                 "active": state["active"].at[slot].set(True),
             }
             return cache, state
-
-        def _tick_fn(p, cache, state, key):
-            mnt, eos = state["mnt"], state["eos"]
-
-            def body(carry, kk):
-                cache, tok, pos, steps, active = carry
-                pos2 = jnp.where(active, pos + 1, pos)
-                with qtensor_use_kernel(scfg.use_kernel), \
-                        qtensor_act_fmt(scfg.act_fmt):
-                    logits, cache = lm_decode(p, cfg, cache, tok[:, None],
-                                              pos2, token_mask=active)
-                # non-finite guard (DESIGN.md §10): a poisoned slot is
-                # done-masked INSIDE the scan — it stops sampling, stops
-                # writing KV, and emits nothing from the bad step on; the
-                # (k, n_slots) poison mask rides the existing per-tick
-                # transfer so the guard costs one reduction, not a sync
-                ok = jnp.isfinite(logits[:, 0]).all(axis=-1)
-                bad = active & ~ok
-                live = active & ok
-                new_tok = jnp.where(live, _sample(logits[:, 0], kk),
-                                    tok).astype(jnp.int32)
-                steps2 = jnp.where(live, steps + 1, steps)
-                emitted = jnp.where(live, new_tok, -1)
-                done = (steps2 >= mnt) | (new_tok == eos) | bad
-                return ((cache, new_tok, pos2, steps2, active & ~done),
-                        (emitted, bad))
-
-            keys = jax.random.split(key, k)
-            carry = (cache, state["tok"], state["pos"], state["steps"],
-                     state["active"])
-            (cache, tok, pos, steps, active), (em, bad) = jax.lax.scan(
-                body, carry, keys)
-            new_state = {"tok": tok, "pos": pos, "steps": steps,
-                         "mnt": mnt, "eos": eos, "active": active}
-            return cache, new_state, em, bad     # em/bad: (k, n_slots)
 
         def _chunk_fn(p, row_cache, toks, start, lens, key):
             with qtensor_use_kernel(scfg.use_kernel), \
@@ -441,47 +453,19 @@ class Scheduler:
             # needs restoring — zero recompute, exact for any KV format
             return _set_state(state, slot, tok, pos, mnt, eos, steps)
 
-        def _tick_paged_fn(p, pool, tables, state, key):
-            mnt, eos = state["mnt"], state["eos"]
-
-            def body(carry, kk):
-                pool, tok, pos, steps, active = carry
-                pos2 = jnp.where(active, pos + 1, pos)
-                with qtensor_use_kernel(scfg.use_kernel), \
-                        qtensor_act_fmt(scfg.act_fmt):
-                    logits, pool = lm_decode(
-                        p, cfg, pool, tok[:, None], pos2,
-                        token_mask=active, block_tables=tables,
-                        block_size=sched.block_size)
-                ok = jnp.isfinite(logits[:, 0]).all(axis=-1)
-                bad = active & ~ok
-                live = active & ok
-                new_tok = jnp.where(live, _sample(logits[:, 0], kk),
-                                    tok).astype(jnp.int32)
-                steps2 = jnp.where(live, steps + 1, steps)
-                emitted = jnp.where(live, new_tok, -1)
-                done = (steps2 >= mnt) | (new_tok == eos) | bad
-                return ((pool, new_tok, pos2, steps2, active & ~done),
-                        (emitted, bad))
-
-            keys = jax.random.split(key, k)
-            carry = (pool, state["tok"], state["pos"], state["steps"],
-                     state["active"])
-            (pool, tok, pos, steps, active), (em, bad) = jax.lax.scan(
-                body, carry, keys)
-            new_state = {"tok": tok, "pos": pos, "steps": steps,
-                         "mnt": mnt, "eos": eos, "active": active}
-            return pool, new_state, em, bad
-
         self._prefill = jax.jit(_prefill_fn)
         if self.paged:
             self._insert_paged = jax.jit(_insert_paged_fn,
                                          donate_argnums=(0, 1))
             self._reattach = jax.jit(_reattach_fn, donate_argnums=(0,))
-            self._tick = jax.jit(_tick_paged_fn, donate_argnums=(1, 3))
+            # (params, pool, tables, state, key)
+            self._tick = jax.jit(
+                lambda p, pool, tables, state, key: tick(p, pool, state,
+                                                         key, tables),
+                donate_argnums=(1, 3))
         else:
             self._insert = jax.jit(_insert_fn, donate_argnums=(0, 1))
-            self._tick = jax.jit(_tick_fn, donate_argnums=(1, 2))
+            self._tick = jax.jit(tick, donate_argnums=(1, 2))
         if self._chunked:
             self._chunk = jax.jit(_chunk_fn, donate_argnums=(1,))
             if self.paged:

@@ -85,6 +85,18 @@ def test_cache_spec_rules():
     s = spec(("unit", "b0_attn", "k", "codes"), (32, 128, 32768, 8, 128),
              MESH, 128)
     assert s == P(None, ("data",), ("model",), None, None)
+    # paged pool in the decode kernel's layout: blocks like a batch, the
+    # block's tokens like a length, on codes (r, nb, bs, C) and scales
+    # (r, nb, g, bs) alike
+    s = spec(("unit", "b0_attn", "k", "codes"), (40, 512, 256, 256), MESH,
+             512)
+    assert s == P(None, ("data",), ("model",), None)
+    s = spec(("unit", "b0_attn", "k", "scale"), (40, 512, 8, 256), MESH,
+             512)
+    assert s == P(None, ("data",), None, ("model",))
+    s = spec(("unit", "b0_attn", "v", "scale"), (40, 273, 8, 256), MESH,
+             273)
+    assert s == P(None, None, None, ("data", "model"))
     # ssm state: heads over model
     s = spec(("unit", "b0_mamba", "ssm"), (9, 1, 80, 64, 64), MESH, 1)
     assert s == P(None, None, "model", None, None)

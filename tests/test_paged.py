@@ -14,8 +14,8 @@ import pytest
 from repro.kernels.decode_attn import (decode_attn_paged,
                                        decode_attn_paged_ref,
                                        decode_attn_ref)
-from repro.models.layers import kv_quantize
-from repro.models.lm import LMConfig, lm_init
+from repro.models.layers import attn_decode_paged, kv_quantize
+from repro.models.lm import LMConfig, init_paged_pool, lm_init
 from repro.serve import (REJECTED, Scheduler, SchedulerConfig,
                          ServeConfig, chaos_plan, check_drained)
 from repro.serve.replay import replay_chaos, sla_workload
@@ -24,51 +24,99 @@ CFG = LMConfig(name="pg", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
                d_ff=128, vocab=128, dtype=jnp.float32, remat=False)
 PARAMS = lm_init(jax.random.PRNGKey(0), CFG)
 
-B, BPS, BS, G, HD = 3, 4, 16, 2, 64
-POS = (5, 63, 150)          # partial, exactly-full, ring-wrapped
+B, BPS, BS, G, HD = 4, 4, 16, 2, 64
+POS = (5, 63, 150, 0)       # partial, exactly-full, ring-wrapped, idle
+R, LAYER = 3, 1             # stacked pool; the middle layer is read
 
 
 def _paged_kv(seed, bits):
-    """Dense quantized ring KV scattered into a shuffled block pool:
-    returns (dense codes, dense scale, pool codes, pool scale)."""
+    """Dense quantized ring KV of rows 0-2, and the same bytes at layer
+    ``LAYER`` of a stacked pool in the kernel's layout (codes (R, nb, bs,
+    G*hd_c), scales (R, nb, G, bs)) behind shuffled block tables; the
+    other layers and the null block hold noise, and row 3 is an idle slot
+    whose table is all null block.  Returns (dense codes, dense scale,
+    pool codes, pool scale, tables)."""
     x = jax.random.normal(jax.random.PRNGKey(seed), (B, BPS * BS, G, HD),
                           jnp.float32)
     q = kv_quantize(x, bits)
     codes, scale = np.asarray(q["codes"]), np.asarray(q["scale"])
-    nb = 1 + B * BPS
+    nb = 1 + (B - 1) * BPS
     rng = np.random.default_rng(seed)
-    tables = rng.permutation(np.arange(1, nb)).reshape(B, BPS)
-    pc = np.zeros((nb, BS) + codes.shape[2:], codes.dtype)
-    ps = np.zeros((nb, BS) + scale.shape[2:], scale.dtype)
-    cb = codes.reshape(B, BPS, BS, *codes.shape[2:])
-    sb = scale.reshape(B, BPS, BS, *scale.shape[2:])
-    for i in range(B):
+    tables = np.zeros((B, BPS), np.int32)
+    tables[:B - 1] = rng.permutation(np.arange(1, nb)).reshape(B - 1, BPS)
+    noise = kv_quantize(jax.random.normal(
+        jax.random.PRNGKey(seed + 1), (R * nb, BS, G, HD)), bits)
+    pc = np.asarray(noise["codes"]).reshape(R, nb, BS, -1).copy()
+    ps = np.swapaxes(np.asarray(noise["scale"])[..., 0].reshape(
+        R, nb, BS, G), 2, 3).copy()
+    cb = codes.reshape(B, BPS, BS, -1)
+    sb = np.swapaxes(scale[..., 0].reshape(B, BPS, BS, G), 2, 3)
+    for i in range(B - 1):
         for j in range(BPS):
-            pc[tables[i, j]] = cb[i, j]
-            ps[tables[i, j]] = sb[i, j]
+            pc[LAYER, tables[i, j]] = cb[i, j]
+            ps[LAYER, tables[i, j]] = sb[i, j]
     return (jnp.asarray(codes), jnp.asarray(scale),
             jnp.asarray(pc), jnp.asarray(ps), jnp.asarray(tables))
 
 
 @pytest.mark.parametrize("bits", [8, 4])
-@pytest.mark.parametrize("window,softcap", [(None, None), (24, 30.0)])
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("softcap", [None, 30.0])
 def test_paged_kernel_matches_oracle(bits, window, softcap):
     kc, ks, kcp, ksp, tables = _paged_kv(1, bits)
     vc, vs, vcp, vsp, _ = _paged_kv(1, bits)   # same tables by seed
     q = jax.random.normal(jax.random.PRNGKey(3), (B, G, 2, HD),
                           jnp.float32)
     pos = jnp.asarray(POS, jnp.int32)
-    got = decode_attn_paged(q, kcp, ksp, vcp, vsp, tables, pos,
+    got = decode_attn_paged(q, kcp, ksp, vcp, vsp, tables, pos, LAYER,
                             bits=bits, window=window, softcap=softcap)
-    want = decode_attn_paged_ref(q, kcp, ksp, vcp, vsp, tables, pos,
+    want = decode_attn_paged_ref(q, kcp, ksp, vcp, vsp, tables, pos, LAYER,
                                  bits=bits, window=window, softcap=softcap)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
     # the paged oracle over a scattered pool IS the dense-ring oracle
-    dense = decode_attn_ref(q, kc, ks, vc, vs, pos, bits=bits,
-                            window=window, softcap=softcap)
-    np.testing.assert_allclose(np.asarray(want), np.asarray(dense),
+    n = B - 1
+    dense = decode_attn_ref(q[:n], kc[:n], ks[:n], vc[:n], vs[:n], pos[:n],
+                            bits=bits, window=window, softcap=softcap)
+    np.testing.assert_allclose(np.asarray(want[:n]), np.asarray(dense),
                                atol=1e-6, rtol=1e-6)
+
+
+def test_paged_kernel_rejects_unstacked_layout():
+    """The kernel names the stored layout it expects."""
+    _, _, kcp, ksp, tables = _paged_kv(1, 4)
+    q = jnp.zeros((B, G, 2, HD), jnp.float32)
+    pos = jnp.asarray(POS, jnp.int32)
+    old = ksp[LAYER][:, :, :, None].swapaxes(1, 2)   # (nb, bs, G, 1)
+    with pytest.raises(ValueError, match=r"k_scale shape .* != \(3, 13, 2, 16\)"):
+        decode_attn_paged(q, kcp, old, kcp, ksp, tables, pos, LAYER, bits=4)
+
+
+@pytest.mark.parametrize("kvq", [False, "int8", "int4"])
+def test_paged_write_touches_one_token_per_row(kvq):
+    """Decoding layer ``r`` writes each row's new token at (r, block,
+    offset) of the stacked pool and leaves every other byte — the other
+    layers included — unchanged."""
+    spec = CFG.attn_spec("attn")
+    pool = init_paged_pool(CFG, 9, 8, dtype=jnp.float32, kv_quant=kvq)
+    pool = jax.tree.map(lambda a: (jax.random.uniform(
+        jax.random.PRNGKey(a.ndim), a.shape) * 100).astype(a.dtype), pool)
+    leaf = pool["unit"]["b0_attn"]
+    tables = jnp.asarray([[3, 1, 4, 2], [5, 6, 7, 8]], jnp.int32)
+    pos = jnp.asarray([13, 30], jnp.int32)    # blocks 1 and 3, offsets 5, 6
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 1, CFG.d_model))
+    p = jax.tree.map(lambda a: a[0], PARAMS["stage"]["b0_attn"]["attn"])
+    _, nk, nv = attn_decode_paged(p, spec, x, pos, leaf["k"], leaf["v"],
+                                  tables, jnp.int32(1))
+    for old, new in ((leaf["k"], nk), (leaf["v"], nv)):
+        for name, a, b in zip(("codes", "scale"), jax.tree.leaves(old),
+                              jax.tree.leaves(new)):
+            a, b = np.asarray(a), np.asarray(b)
+            tok = 3 if (kvq and name == "scale") else 2
+            changed = np.argwhere(np.any(
+                a != b, axis=tuple(d for d in range(a.ndim)
+                                   if d not in (0, 1, tok))))
+            assert {tuple(c) for c in changed} == {(1, 1, 5), (1, 8, 6)}
 
 
 # --------------------------------------------------------------------------

@@ -270,18 +270,22 @@ def test_slot_pool_bookkeeping():
 
 
 def test_slot_pool_cache_shardings_cover_scheduler_pool():
-    """The slot-pool cache (batch dim = n_slots) flows through the same
-    cache sharding rules as static decode — including packed-int4 KV
-    codes (uint8, halved trailing dim)."""
+    """The slot-pool cache (batch dim = n_slots) and the paged block pool
+    (batch dim = n_blocks) flow through the same cache sharding rules as
+    static decode — including packed-int4 KV codes (uint8, halved
+    trailing dim)."""
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import cache_shardings
+    from repro.models.lm import init_paged_pool
 
     mesh = make_mesh((1, 1), ("data", "model"))
-    for kv in (False, "int8", "int4"):
+    for kv, paged in [(kv, paged) for kv in (False, "int8", "int4")
+                      for paged in (False, True)]:
+        init = init_paged_pool if paged else init_cache
         pool = jax.eval_shape(
-            lambda kv=kv: init_cache(CFG, 4, 64, dtype=jnp.float32,
-                                     kv_quant=kv))
+            lambda kv=kv, init=init: init(CFG, 4, 64, dtype=jnp.float32,
+                                          kv_quant=kv))
         sh = cache_shardings(mesh, pool, batch=4)
         leaf = jax.tree_util.tree_leaves_with_path(sh)
         assert leaf                     # every leaf got a sharding

@@ -71,14 +71,19 @@ def _serving_program(sch, which):
 ])
 def test_serving_programs_carry_scopes(which, want):
     """The decode tick, the prefill chunk and the paged insert carry the
-    program's scopes in their op metadata; the pool's per-layer reads and
-    writes (the layer scan's carry, the block-table gather and write, the
-    insert's scatter, the cache quantizer) are under ``kv_cache``."""
+    program's scopes in their op metadata; the pool's reads and writes
+    (the tick's block-table gather and in-place token write, the chunk's
+    carry, the insert's scatter, the cache quantizer) are under
+    ``kv_cache``.  The paged tick never slices a layer out of the pool
+    nor writes one back."""
     sch = _sched(lm_init(jax.random.PRNGKey(0), CFG))
     names = _op_names(_serving_program(sch, which))
     assert want <= _scopes(names), (which, _scopes(names))
     kv_ops = {n.rsplit("/", 1)[-1] for n in names if spans.KV_CACHE in n}
     if which == "tick":
+        assert {"gather", "scatter"} <= kv_ops, kv_ops
+        assert not {"dynamic_slice", "dynamic_update_slice"} & kv_ops, kv_ops
+    if which == "chunk":
         assert {"dynamic_slice", "dynamic_update_slice"} <= kv_ops, kv_ops
     if which == "insert":
         assert "scatter" in kv_ops, kv_ops
