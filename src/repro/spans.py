@@ -54,10 +54,11 @@ MLP = "mlp"
 HEAD = "head"
 KV_CACHE = "kv_cache"          # every read and write of a stacked/pooled cache
 FORWARD_BACKWARD = "forward_backward"
+GRAD_SYNC = "grad_sync"        # the gradient's move into the update's layout
 UPDATE = "update"              # the optimizer chain and its apply
 SAMPLE = "sample"
-SCOPES = (EMBED, ATTN, MLP, HEAD, KV_CACHE, FORWARD_BACKWARD, UPDATE,
-          SAMPLE)
+SCOPES = (EMBED, ATTN, MLP, HEAD, KV_CACHE, FORWARD_BACKWARD, GRAD_SYNC,
+          UPDATE, SAMPLE)
 
 
 def span(name: str, **ids) -> jax.profiler.TraceAnnotation:

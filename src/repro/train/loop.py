@@ -294,7 +294,9 @@ def make_train_step(cfg: LMConfig, tcfg: TrainConfig, optimizer,
             ce_ex = aux.pop("ce_ex", None)
 
         if grad_shardings is not None:
-            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
+            with jax.named_scope(spans.GRAD_SYNC):
+                grads = jax.lax.with_sharding_constraint(grads,
+                                                         grad_shardings)
 
         # on-device non-finite guard (DESIGN.md §11): ok_loss gates the
         # update through the chain — a fused core folds it into its

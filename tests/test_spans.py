@@ -112,6 +112,41 @@ def test_train_step_carries_scopes():
                    for n in names if spans.UPDATE in _scopes([n]))
 
 
+
+def test_grad_sync_scope_marks_the_gradient_constraint():
+    """``grad_sync`` is a program scope; the constraint that moves the
+    gradient into the update's layout (``grad_shardings``) is under it and
+    under neither ``forward_backward`` nor ``update``.  Without
+    ``grad_shardings`` there is no such constraint."""
+    from repro.distributed.context import clear_constraints
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import shard_setup
+
+    assert spans.GRAD_SYNC == "grad_sync" and spans.GRAD_SYNC in spans.SCOPES
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES)
+    tcfg = TrainConfig(quant=QuantConfig(method="lotion", fmt_name="int4",
+                                         lam=1.0))
+    opt = make_optimizer(tcfg, adamw(lambda count: 1e-3))
+    state = init_state(lm_init(jax.random.PRNGKey(0), CFG), opt)
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+             "labels": jnp.zeros((2, 16), jnp.int32)}
+    mesh = make_mesh((1, 1), ("data", "model"))
+    try:
+        with mesh:
+            sh = shard_setup(CFG, opt, mesh)
+            texts = [jax.jit(make_train_step(CFG, tcfg, opt, grad_shardings=g))
+                     .lower(state, batch).as_text(debug_info=True)
+                     for g in (sh["params"], None)]
+    finally:
+        clear_constraints()
+    locs = [re.findall(r'loc\("([^"]*)"', t) for t in texts]
+    synced = [n for n in locs[0] if spans.GRAD_SYNC in _scopes([n])]
+    assert synced and all(n.endswith("sharding_constraint") for n in synced)
+    assert not any({spans.FORWARD_BACKWARD, spans.UPDATE} & _scopes([n])
+                   for n in synced)
+    assert not any(spans.GRAD_SYNC in _scopes([n]) for n in locs[1])
+
+
 def _host_spans(trace_dir):
     """(name, start, end, stats) of the program's spans in a trace."""
     f = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
